@@ -65,6 +65,9 @@ def main() -> None:
         collect_rows,
         emit_bench_json,
     )
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     fast = args.fast or args.smoke
     ctx = BenchContext(n=6000 if fast else 12000,

@@ -61,6 +61,14 @@ def _medoid(x: np.ndarray) -> int:
 MAX_REV_ADD = 8  # reverse-edge additions kept per destination per batch
 
 
+def _bucket(n: int) -> int:
+    """Row count a device call is padded to: the next power of two (at
+    least 64), so calls with varying row counts reuse a few compiled
+    programs. Every vmapped row is independent, so pad rows change no
+    real row's result."""
+    return 1 << max(6, (n - 1).bit_length())
+
+
 def _reverse_edges(pg: PG, ids: np.ndarray, alpha2: float):
     """Insert reverse edges id -> (its new nbrs); prune overflowing rows.
 
@@ -114,10 +122,15 @@ def _reverse_edges(pg: PG, ids: np.ndarray, alpha2: float):
         diffs = pg.A[cand_safe] - pg.A[rows][:, None, :]
         cd = np.einsum("bcd,bcd->bc", diffs, diffs).astype(np.float32)
         cd = np.where(cand < pg.n_nodes, cd, np.float32(3.4e38))
+        # the overflow count differs every call: pad it (``_bucket``)
+        # with rows that have no live candidate, and drop them after
+        pad = _bucket(len(rows)) - len(rows)
+        cand = np.pad(cand, ((0, pad), (0, 0)), constant_values=m_cap)
+        cd = np.pad(cd, ((0, pad), (0, 0)), constant_values=3.4e38)
         pruned = np.asarray(robust_prune(
             jnp.asarray(cand), jnp.asarray(cd), jnp.asarray(pg.A),
             jnp.int32(pg.n_nodes), jnp.float32(alpha2), R=R))
-        pg.nbrs[rows, :R] = pruned
+        pg.nbrs[rows, :R] = pruned[:len(rows)]
 
 
 def build_pg(x: np.ndarray, R: int = 16, L: int = 48,
@@ -192,11 +205,15 @@ def repair_connectivity(pg: PG, sample: int = 256):
 
 def _insert_batch(pg: PG, ids: np.ndarray, L: int, alpha2: float):
     A_dev, nbrs_dev, n_nodes, entry = pg.device_arrays()
-    q = jnp.asarray(pg.A[ids])
-    res = greedy_search(A_dev, nbrs_dev, n_nodes, entry, q, L=L, K=L)
+    b = len(ids)
+    pad = _bucket(b) - b
+    q = pg.A[ids]
+    q = np.concatenate([q, np.repeat(q[:1], pad, axis=0)])
+    res = greedy_search(A_dev, nbrs_dev, n_nodes, entry, jnp.asarray(q),
+                        L=L, K=L)
     # candidates: beam results + current neighbors + visited path
-    cand = np.concatenate([np.asarray(res.ids), np.asarray(res.path),
-                           pg.nbrs[ids]], axis=1)
+    cand = np.concatenate([np.asarray(res.ids)[:b],
+                           np.asarray(res.path)[:b], pg.nbrs[ids]], axis=1)
     m_cap = pg.m_cap
     cand_safe = np.minimum(cand, m_cap - 1)
     diffs = pg.A[cand_safe] - pg.A[ids][:, None, :]
@@ -204,15 +221,20 @@ def _insert_batch(pg: PG, ids: np.ndarray, L: int, alpha2: float):
     invalid = (cand >= pg.n_nodes) | (cand == ids[:, None])
     cd = np.where(invalid, np.float32(3.4e38), cd)
     pruned = np.asarray(robust_prune(
-        jnp.asarray(cand.astype(np.int32)), jnp.asarray(cd), A_dev,
-        jnp.int32(pg.n_nodes), jnp.float32(alpha2), R=pg.R_prune))
-    pg.nbrs[ids, :pg.R_prune] = pruned
+        jnp.asarray(np.pad(cand.astype(np.int32), ((0, pad), (0, 0)),
+                           constant_values=m_cap)),
+        jnp.asarray(np.pad(cd, ((0, pad), (0, 0)), constant_values=3.4e38)),
+        A_dev, jnp.int32(pg.n_nodes), jnp.float32(alpha2), R=pg.R_prune))
+    pg.nbrs[ids, :pg.R_prune] = pruned[:b]
     _reverse_edges(pg, ids, alpha2)
 
 
 def insert_nodes(pg: PG, new_x: np.ndarray, L: int = 48,
-                 alpha: float = 1.2) -> np.ndarray:
-    """Insert new points into the arena (PAG promotion). Returns their ids."""
+                 alpha: float = 1.2, batch: int = 2048) -> np.ndarray:
+    """Insert new points into the arena (PAG promotion). Returns their ids.
+
+    Links them ``batch`` at a time: one traversal's visited masks take
+    ``batch x m_cap`` bytes of device memory."""
     k = new_x.shape[0]
     assert pg.n_nodes + k <= pg.m_cap, "PG arena capacity exceeded"
     ids = np.arange(pg.n_nodes, pg.n_nodes + k, dtype=np.int32)
@@ -223,7 +245,8 @@ def insert_nodes(pg: PG, new_x: np.ndarray, L: int = 48,
         rng = np.random.default_rng(int(pg.n_nodes))
         pg.nbrs[ids, pg.R_prune:] = rng.integers(
             0, pg.n_nodes, size=(k, n_rand))
-    _insert_batch(pg, ids, L, float(alpha * alpha))
+    for s in range(0, k, batch):
+        _insert_batch(pg, ids[s:s + batch], L, float(alpha * alpha))
     return ids
 
 

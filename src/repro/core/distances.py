@@ -16,10 +16,14 @@ def sq_norms(x: jax.Array) -> jax.Array:
 
 
 def cdist2(q: jax.Array, x: jax.Array) -> jax.Array:
-    """Squared L2 distances [Q, N] = |q|^2 - 2 q.x + |x|^2 (MXU-friendly)."""
+    """Squared L2 distances [Q, N] = |q|^2 - 2 q.x + |x|^2 (MXU-friendly).
+
+    The matmul runs at full f32 precision: a TPU's default would round
+    the operands to bf16, which reorders near neighbours."""
     q = q.astype(jnp.float32)
     x = x.astype(jnp.float32)
-    d2 = (sq_norms(q)[:, None] - 2.0 * (q @ x.T) + sq_norms(x)[None, :])
+    qx = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = sq_norms(q)[:, None] - 2.0 * qx + sq_norms(x)[None, :]
     return jnp.maximum(d2, 0.0)
 
 

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 from repro.core.distances import cdist2
 from repro.core.pag import PAG
 from repro.core.search import SearchConfig, SearchStats, search_pag
@@ -134,9 +132,7 @@ def make_anns_serve_step(mesh: Mesh, k: int = 100):
             local_ids = jnp.take_along_axis(rows_blk, idx, axis=1)
             r = jax.lax.axis_index(axes[0])
             for a in axes[1:]:
-                # axis sizes are static from the mesh (jax.lax.axis_size
-                # only exists on newer jax)
-                r = r * mesh.shape[a] + jax.lax.axis_index(a)
+                r = r * jax.lax.axis_size(a) + jax.lax.axis_index(a)
             gids = local_ids + r * n_local
             for a in axes:                                # hierarchical merge
                 neg = jax.lax.all_gather(neg, a, axis=1, tiled=True)
@@ -145,7 +141,7 @@ def make_anns_serve_step(mesh: Mesh, k: int = 100):
                 gids = jnp.take_along_axis(gids, pos, axis=1)
             return gids, -neg
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(*([None] * 2)), P(axes, None), P(None, None)),
             out_specs=(P(None, None), P(None, None)),
@@ -210,7 +206,7 @@ def make_anns_assign_step(mesh: Mesh, k: int = 8, row_chunk: int = 4096,
             gids = jnp.take_along_axis(gids, pos, axis=1)
             return gids, -neg
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(dp_spec, None), P("model", None)),
             out_specs=(P(dp_spec, None), P(dp_spec, None)),

@@ -251,7 +251,7 @@ def build_pag(x: np.ndarray, *, p: float = 0.2, k: int = 8,
                 plist = _grow(plist, -1, extra)
                 pcount = _grow(pcount, 0, extra)
                 m_cap = pg.m_cap
-            new_ids = insert_nodes(pg, x[pending], L=L_build)
+            new_ids = insert_nodes(pg, x[pending], L=L_build, batch=batch)
             node_src[new_ids] = pending
             r_new = _neighbor_radii(pg, new_ids, gamma1)
             radius[new_ids] = np.minimum(r_new, d_o) if use_drs else \

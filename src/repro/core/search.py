@@ -177,6 +177,7 @@ def write_partitions(pag: PAG, x: np.ndarray, store: ObjectStore,
         cb = train_pq(np.asarray(x, np.float32), M=pq_m, seed=pq_seed)
         for key in codebook_keys(prefix, replicas):
             store.put(key, cb.centroids)
+    vecs = []
     for pid in range(pag.n_parts):
         cnt = int(pag.pcount[pid])
         ids = pag.plist[pid, :cnt]
@@ -186,7 +187,15 @@ def write_partitions(pag: PAG, x: np.ndarray, store: ObjectStore,
         for key in replica_keys(prefix, pid, n_shards, replicas):
             store.put(key, obj)
         if cb is not None:
-            codes = encode_pq(cb, np.asarray(obj[:, 1:], np.float32))
+            vecs.append(obj[:, 1:])
+    if cb is not None:
+        # one bulk encode: rows are encoded independently, so each slice
+        # equals encoding its partition on its own
+        codes_all = encode_pq(cb, np.concatenate(vecs))
+        start = 0
+        for pid, v in enumerate(vecs):
+            codes = codes_all[start:start + len(v)]
+            start += len(v)
             for key in replica_keys(prefix, pid, n_shards, replicas,
                                     obj="pq"):
                 store.put(key, codes)
@@ -322,29 +331,36 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
                prefetched: Optional[Dict[str, Tuple[np.ndarray, float]]]
                = None,
                prefetch_probes: Optional[List[List[int]]] = None,
-               trace_t0_s: float = 0.0
+               trace_t0_s: float = 0.0, pad_rows: int = 0
                ) -> Tuple[np.ndarray, np.ndarray, SearchStats]:
     """Returns (result ids [Q, k] original ids, sq-dists [Q, k], stats).
 
-    ``prefetched`` / ``prefetch_probes`` / ``trace_t0_s`` serve the
-    micro-batch pipeline (``serving.engine.AnnsFrontend``): objects the
-    previous batch already fetched (key -> (object, residual latency)),
-    the predicted probe orders of the next batch (the batched engine
-    issues their wave mid-batch and returns it as ``stats.prefetch``),
-    and the absolute event-clock offset of this batch's span tree
-    (so frontend and batch tracks share one clock in the trace)."""
+    ``prefetched`` / ``prefetch_probes`` / ``trace_t0_s`` / ``pad_rows``
+    serve the micro-batch pipeline (``serving.engine.AnnsFrontend``):
+    objects the previous batch already fetched (key -> (object, residual
+    latency)), the predicted probe orders of the next batch (the batched
+    engine issues their wave mid-batch and returns it as
+    ``stats.prefetch``), the absolute event-clock offset of this batch's
+    span tree (so frontend and batch tracks share one clock in the
+    trace), and the row count every device launch is padded to (a short
+    last micro-batch then reuses the full batch's compiled programs;
+    storage, clocks and results see only the real rows)."""
     compute = compute or ComputeModel()
     pg = pag.pg
+    q_count = queries.shape[0]
+    q_dev = np.asarray(queries, np.float32)
+    if pad_rows > q_count > 0:  # pad with copies of a real query
+        q_dev = np.concatenate(
+            [q_dev, np.repeat(q_dev[:1], pad_rows - q_count, axis=0)])
     A_dev, nbrs_dev, n_nodes, entry = pg.device_arrays()
     res = greedy_search(A_dev, nbrs_dev, n_nodes, entry,
-                        jnp.asarray(queries), L=cfg.L, K=cfg.L)
-    path_all = np.asarray(res.path)
-    path_all_d2 = np.asarray(res.path_dists)
-    hops = np.asarray(res.n_hops)
-    beam_ids = np.asarray(res.ids)
-    beam_d2 = np.asarray(res.dists)
+                        jnp.asarray(q_dev), L=cfg.L, K=cfg.L)
+    path_all = np.asarray(res.path)[:q_count]
+    path_all_d2 = np.asarray(res.path_dists)[:q_count]
+    hops = np.asarray(res.n_hops)[:q_count]
+    beam_ids = np.asarray(res.ids)[:q_count]
+    beam_d2 = np.asarray(res.dists)[:q_count]
 
-    q_count = queries.shape[0]
     R_edges = pg.nbrs.shape[1]
     traversal_s = [compute.search_hop(int(hops[qi]) * R_edges, x_dim)
                    for qi in range(q_count)]
@@ -370,7 +386,7 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
                           degraded=degraded, compute=compute,
                           dead_shard_fallback=dead_shard_fallback,
                           record=rec, prefetched=prefetched)
-    scan = ScanStage(cfg.scan_block)
+    scan = ScanStage(cfg.scan_block, pad_rows=pad_rows)
 
     codebook, cb_lat = None, 0.0
     if pq:

@@ -37,8 +37,17 @@ def dedup_first(ids: np.ndarray) -> np.ndarray:
 class ScanStage:
     """The compute stage: one masked Pallas launch per scan kind."""
 
-    def __init__(self, scan_block: int = 256):
+    def __init__(self, scan_block: int = 256, pad_rows: int = 0):
         self.scan_block = scan_block
+        self.pad_rows = pad_rows    # launch at least this many query rows
+
+    def _shape(self, q_count: int, c_max: int) -> Tuple[int, int]:
+        """Launch shape (rows, pool width): rows padded to ``pad_rows``,
+        width rounded up to a multiple of ``scan_block``, so the jitted
+        kernels see few distinct shapes across micro-batches. Padded rows
+        and columns carry id -1 and never reach a result."""
+        width = -(-c_max // self.scan_block) * self.scan_block
+        return max(q_count, self.pad_rows), width
 
     # ---------------------------------------------------------- exact topk
     def topk(self, queries: np.ndarray, pool_ids: List[np.ndarray],
@@ -52,8 +61,11 @@ class ScanStage:
         if c_max == 0:
             return (np.full((q_count, k), -1, np.int64),
                     np.full((q_count, k), INF, np.float32))
-        ids_pad = np.full((q_count, c_max), -1, np.int32)
-        vecs_pad = np.zeros((q_count, c_max, d), np.float32)
+        rows, width = self._shape(q_count, c_max)
+        q_pad = np.zeros((rows, d), np.float32)
+        q_pad[:q_count] = queries
+        ids_pad = np.full((rows, width), -1, np.int32)
+        vecs_pad = np.zeros((rows, width, d), np.float32)
         for qi in range(q_count):
             n = len(pool_ids[qi])
             if n:
@@ -62,9 +74,10 @@ class ScanStage:
         tracer = get_tracer()
         t0 = time.perf_counter() if tracer.enabled else 0.0
         d2, ids = ops.l2_topk_masked(
-            jnp.asarray(queries, jnp.float32), jnp.asarray(vecs_pad),
+            jnp.asarray(q_pad), jnp.asarray(vecs_pad),
             jnp.asarray(ids_pad), k=k, block_c=self.scan_block)
-        out = np.asarray(ids).astype(np.int64), np.asarray(d2)
+        out = (np.asarray(ids)[:q_count].astype(np.int64),
+               np.asarray(d2)[:q_count])
         if tracer.enabled:  # np.asarray forced the async dispatch above
             dt = time.perf_counter() - t0
             tracer.wall_span("pallas_launch l2_topk", dt,
@@ -124,20 +137,23 @@ class ScanStage:
         if c_max == 0:
             return [[] for _ in range(q_count)]
         m = codebook.M
-        codes_pad = np.zeros((q_count, c_max, m), np.uint8)
-        pos_pad = np.full((q_count, c_max), -1, np.int32)
+        rows, width = self._shape(q_count, c_max)
+        codes_pad = np.zeros((rows, width, m), np.uint8)
+        pos_pad = np.full((rows, width), -1, np.int32)
         for qi in range(q_count):
             n = len(cand_pids[qi])
             if n:
                 codes_pad[qi, :n] = cand_codes[qi]
                 pos_pad[qi, :n] = np.arange(n, dtype=np.int32)
-        luts = adc_lut_batch(codebook, np.asarray(queries, np.float32))
+        luts = np.zeros((rows, m, 256), np.float32)
+        luts[:q_count] = adc_lut_batch(codebook,
+                                       np.asarray(queries, np.float32))
         tracer = get_tracer()
         t0 = time.perf_counter() if tracer.enabled else 0.0
         _, pos = ops.pq_adc_masked(
             jnp.asarray(luts), jnp.asarray(codes_pad),
             jnp.asarray(pos_pad), k=rerank_k, block_c=self.scan_block)
-        pos = np.asarray(pos)
+        pos = np.asarray(pos)[:q_count]
         if tracer.enabled:  # np.asarray forced the async dispatch above
             dt = time.perf_counter() - t0
             tracer.wall_span("pallas_launch pq_adc", dt,

@@ -67,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, *, interpret: bool):
     """Single head: q [Sq, d]; k, v [Sk, d] -> [Sq, d].
     Batched/bheaded use goes through ops.flash_attention (vmap)."""
     sq, d = q.shape

@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On the CPU container the kernels execute in interpret mode; on TPU set
-``interpret=False`` (the default flips on TPU backends automatically).
+The raw kernels take ``interpret`` as a required keyword. These wrappers
+resolve ``interpret=None`` from the default backend: compiled by Mosaic
+on a TPU, the Pallas interpreter everywhere else (the CPU test tier).
 """
 from __future__ import annotations
 
@@ -15,14 +16,15 @@ from repro.kernels import l2_topk as _l2
 from repro.kernels import pq_adc as _pq
 
 
-def _default_interpret() -> bool:
+def default_interpret() -> bool:
+    """What ``interpret=None`` resolves to on this process's backend."""
     return jax.default_backend() != "tpu"
 
 
 def l2_topk(q, x, k: int = 10, block_n: int = 512,
             interpret: bool | None = None):
     """q [Q, d], x [N, d] -> (d2 [Q, k] ascending, ids [Q, k])."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _l2.l2_topk(q, x, k=k, block_n=block_n, interpret=interpret)
 
 
@@ -30,14 +32,14 @@ def l2_topk_masked(q, pools, ids, k: int = 10, block_c: int = 256,
                    interpret: bool | None = None):
     """q [Q, d], pools [Q, C, d], ids [Q, C] (-1 pads ragged rows)
     -> (d2 [Q, k] ascending, ids [Q, k]); short rows pad (3.4e38, -1)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _l2.l2_topk_masked(q, pools, ids, k=k, block_c=block_c,
                               interpret=interpret)
 
 
 def pq_adc(lut, codes, block_n: int = 1024, interpret: bool | None = None):
     """lut [M, 256] f32, codes [N, M] -> dists [N] f32."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _pq.pq_adc(lut, codes, block_n=block_n, interpret=interpret)
 
 
@@ -46,7 +48,7 @@ def pq_adc_masked(luts, codes, ids, k: int = 10, block_c: int = 256,
     """luts [Q, M, 256] f32, codes [Q, C, M], ids [Q, C] (-1 pads ragged
     rows) -> (d2 [Q, k] ascending, ids [Q, k]); short rows pad
     (3.4e38, -1)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _pq.pq_adc_masked(luts, codes, ids, k=k, block_c=block_c,
                              interpret=interpret)
 
@@ -54,7 +56,7 @@ def pq_adc_masked(luts, codes, ids, k: int = 10, block_c: int = 256,
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, interpret: bool | None = None):
     """q [B, H, Sq, d]; k, v [B, H, Sk, d] -> [B, H, Sq, d]."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     fn = functools.partial(_fa.flash_attention, causal=causal,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret)

@@ -10,9 +10,10 @@ the [M, 256] LUT stays resident.
 
 ``pq_adc_masked`` mirrors ``l2_topk_masked``: every query of a batch
 carries its own LUT and its own ragged candidate pool (code rows padded
-with id -1); one launch streams the pools in [Q, BC, M] blocks, keeps a
-running per-query top-k in VMEM, and returns the ADC-nearest candidates
-of every query — the selection stage of the PQ-compressed probe wave.
+with id -1); one launch streams the pools in [BLOCK_Q, M, BC] blocks,
+keeps a running per-query top-k in VMEM, and returns the ADC-nearest
+candidates of every query — the selection stage of the PQ-compressed
+probe wave.
 """
 from __future__ import annotations
 
@@ -21,8 +22,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.l2_topk import _select_topk
+from repro.kernels.l2_topk import BLOCK_Q, _select_topk
 
 
 def _kernel(lut_ref, codes_ref, out_ref, *, m: int):
@@ -41,8 +43,8 @@ def _kernel(lut_ref, codes_ref, out_ref, *, m: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def pq_adc(lut: jax.Array, codes: jax.Array, block_n: int = 1024,
-           interpret: bool = True) -> jax.Array:
+def pq_adc(lut: jax.Array, codes: jax.Array, block_n: int = 1024, *,
+           interpret: bool) -> jax.Array:
     """lut [M, 256] f32; codes [N, M] int32/uint8 -> dists [N] f32."""
     m = lut.shape[0]
     n = codes.shape[0]
@@ -68,45 +70,51 @@ def pq_adc(lut: jax.Array, codes: jax.Array, block_n: int = 1024,
 
 def _masked_kernel(lut_ref, codes_ref, id_ref, out_d_ref, out_i_ref, *,
                    k: int, m: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_d_ref[...] = jnp.full_like(out_d_ref, 3.4e38)
         out_i_ref[...] = jnp.full_like(out_i_ref, -1)
 
-    luts = lut_ref[...]                        # [Q, M, 256] resident
-    codes = codes_ref[...]                     # [Q, BC, M] streamed block
-    ids = id_ref[...]                          # [Q, BC] (-1 = padding)
-    qn, bc = codes.shape[0], codes.shape[1]
-    acc = jnp.zeros((qn, bc), jnp.float32)
-    for sub in range(m):                       # M static, unrolled
-        onehot = (jax.lax.broadcasted_iota(
-            jnp.int32, (qn, bc, 256), 2)
-            == codes[:, :, sub][:, :, None]).astype(jnp.float32)
-        # per-query batched [BC, 256] @ [256] on the MXU
-        acc = acc + jax.lax.dot_general(
-            onehot, luts[:, sub, :], (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+    ids = id_ref[...]                          # [BQ, BC] (-1 = padding)
+    bq, bc = ids.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (bq, bc), 0)
+    code_iota = jax.lax.broadcasted_iota(jnp.int32, (256, bc), 0)
+
+    def query(r, acc):
+        def subspace(sub, part):
+            codes = codes_ref[r, pl.ds(sub, 1), :]            # [1, BC]
+            onehot_t = (code_iota == codes).astype(jnp.float32)  # [256, BC]
+            # every tile query's LUT row against query r's one-hot on
+            # the MXU; HIGHEST keeps the looked-up values exact in f32
+            return part + jax.lax.dot_general(
+                lut_ref[sub], onehot_t, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)          # [BQ, BC]
+        part = jax.lax.fori_loop(0, m, subspace,
+                                 jnp.zeros((bq, bc), jnp.float32))
+        return jnp.where(row == r, part, acc)
+
+    acc = jax.lax.fori_loop(0, bq, query, jnp.zeros((bq, bc), jnp.float32))
     d2 = jnp.where(ids >= 0, acc, 3.4e38)      # mask ragged padding
 
-    merged_d = jnp.concatenate([out_d_ref[...], d2], axis=1)
-    merged_i = jnp.concatenate([out_i_ref[...], ids], axis=1)
-    _select_topk(merged_d, merged_i, out_d_ref, out_i_ref, k)
+    out_d_ref[...], out_i_ref[...] = _select_topk(
+        out_d_ref[...], out_i_ref[...], d2, ids, k)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "block_c", "interpret"))
 def pq_adc_masked(luts: jax.Array, codes: jax.Array, ids: jax.Array,
-                  k: int = 10, block_c: int = 256,
-                  interpret: bool = True):
+                  k: int = 10, block_c: int = 256, *, interpret: bool):
     """Ragged per-query PQ pools -> per-query ADC top-k.
 
     luts [Q, M, 256] f32 (one ADC table per query); codes [Q, C, M]
     uint8/int32; ids [Q, C] int32 candidate ids with -1 marking ragged
     padding. Returns (d2 [Q, k] ascending, ids [Q, k]); rows shorter
     than k pad with (3.4e38, -1). One launch scores the compressed
-    pools of ALL queries of a batch (the PQ probe wave's hot loop)."""
+    pools of ALL queries of a batch (the PQ probe wave's hot loop),
+    tiled BLOCK_Q queries x ``block_c`` candidates per grid step; the
+    tile's LUTs ride as [M, BLOCK_Q, 256] and its codes as
+    [BLOCK_Q, M, block_c] so each one-hot is a [256, block_c] slab."""
     qn, m = luts.shape[0], luts.shape[1]
     c = codes.shape[1]
     if c == 0:  # empty pools: all rows pad
@@ -114,31 +122,38 @@ def pq_adc_masked(luts: jax.Array, codes: jax.Array, ids: jax.Array,
                 jnp.full((qn, k), -1, jnp.int32))
     codes = codes.astype(jnp.int32)
     block_c = min(block_c, c)
-    pad = (-c) % block_c
-    if pad:
-        codes = jnp.pad(codes, ((0, 0), (0, pad), (0, 0)))
-        ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-    c_pad = c + pad
+    pad_c = (-c) % block_c
+    pad_q = (-qn) % BLOCK_Q
+    if pad_c or pad_q:
+        luts = jnp.pad(luts, ((0, pad_q), (0, 0), (0, 0)))
+        codes = jnp.pad(codes, ((0, pad_q), (0, pad_c), (0, 0)))
+        ids = jnp.pad(ids, ((0, pad_q), (0, pad_c)), constant_values=-1)
+    q_pad, c_pad = qn + pad_q, c + pad_c
+    luts_t = jnp.transpose(luts, (1, 0, 2))      # [M, Q, 256]
+    codes_t = jnp.transpose(codes, (0, 2, 1))    # [Q, M, C]
 
-    grid = (c_pad // block_c,)
+    grid = (q_pad // BLOCK_Q, c_pad // block_c)
     out_d, out_i = pl.pallas_call(
         functools.partial(_masked_kernel, k=k, m=m),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((qn, m, 256), lambda i: (0, 0, 0)),  # LUTs resident
-            pl.BlockSpec((qn, block_c, m), lambda i: (0, i, 0)),
-            pl.BlockSpec((qn, block_c), lambda i: (0, i)),
+            pl.BlockSpec((m, BLOCK_Q, 256), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((BLOCK_Q, m, block_c), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((BLOCK_Q, block_c), lambda i, j: (i, j)),
         ],
         out_specs=[
-            pl.BlockSpec((qn, k), lambda i: (0, 0)),          # running top-k
-            pl.BlockSpec((qn, k), lambda i: (0, 0)),
+            pl.BlockSpec((BLOCK_Q, k), lambda i, j: (i, 0)),  # running top-k
+            pl.BlockSpec((BLOCK_Q, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((qn, k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, k), jnp.int32),
+            jax.ShapeDtypeStruct((q_pad, k), jnp.float32),
+            jax.ShapeDtypeStruct((q_pad, k), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(luts, codes, ids)
+    )(luts_t, codes_t, ids)
+    out_d, out_i = out_d[:qn], out_i[:qn]
     valid = out_i >= 0
     out_d = jnp.where(valid, out_d, 3.4e38)
     return out_d, out_i

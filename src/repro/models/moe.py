@@ -131,8 +131,6 @@ def _moe_local(params, x, cfg):
 
 def _moe_sharded(params, x, cfg, mesh, dist):
     """shard_map expert parallelism (see module docstring)."""
-    from repro.distributed.compat import shard_map
-
     b, s, d = x.shape
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp = 1
@@ -167,7 +165,7 @@ def _moe_sharded(params, x, cfg, mesh, dist):
         out = jax.lax.psum(out, "model")
         return out.reshape(bb, ss, dd)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_down_spec),
         out_specs=x_spec,

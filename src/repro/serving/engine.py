@@ -152,7 +152,8 @@ class AnnsFrontend:
         batch = np.stack([q for _, q, _ in chunk])
         waits = [now - t0 for _, _, t0 in chunk]
         t0 = self._clock_s
-        kw = {}
+        # a short chunk launches at the full batch's shapes (no recompile)
+        kw = {"pad_rows": self.max_batch}
         if self._handle is not None:
             # the previous chunk prefetched this chunk's probe wave;
             # pay only each object's residual latency past our start

@@ -1,6 +1,9 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
 see the real single CPU device; only launch/dryrun.py forces 512."""
-import numpy as np
+import importlib.util
+import sys
+from pathlib import Path
+
 import pytest
 
 
@@ -32,3 +35,14 @@ def pag_store(built_pag, small_ds):
     store = ObjectStore(StorageConfig.preset("mem"))
     write_partitions(built_pag, small_ds.base, store, n_shards=4)
     return store
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    """The repo-root ``chip_smoke.py`` script, imported as a module."""
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = mod  # its dataclasses resolve the module
+    spec.loader.exec_module(mod)
+    return mod

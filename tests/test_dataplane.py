@@ -25,6 +25,40 @@ def _fresh_store(built_pag, ds, kind="dfs", seed=7, n_shards=4, **kw):
     return store
 
 
+# ------------------------------------------------------------ scan layer
+
+def test_scan_stage_reuses_launch_shapes_across_batches():
+    """Pool widths round up to a multiple of scan_block and rows pad to
+    pad_rows, so a second batch with other counts compiles nothing."""
+    import jax
+
+    from repro.dataplane import ScanStage
+    rng = np.random.default_rng(0)
+    d, k = 8, 4
+    scan = ScanStage(scan_block=128, pad_rows=8)
+
+    def batch(q, lens):
+        pools = [rng.standard_normal((n, d)).astype(np.float32)
+                 for n in lens]
+        ids = [np.arange(n) for n in lens]
+        return rng.standard_normal((q, d)).astype(np.float32), ids, pools
+
+    scan.topk(*batch(5, [3, 100, 7, 60, 1]), k)
+    built = []
+    listener = lambda event, *_, **__: built.append(event)  # noqa: E731
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        queries, ids, pools = batch(3, [120, 9, 40])
+        out_ids, out_d2 = scan.topk(queries, ids, pools, k)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert "/jax/core/compile/backend_compile_duration" not in built
+    assert out_ids.shape == (3, k) and out_d2.shape == (3, k)
+    for qi in range(3):  # the padded launch still answers each row
+        want = np.argsort(((pools[qi] - queries[qi]) ** 2).sum(1))[:k]
+        assert set(out_ids[qi].tolist()) == set(want.tolist())
+
+
 # ------------------------------------------------------------ plan layer
 
 def test_keyspace_v2_layout():

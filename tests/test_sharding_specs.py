@@ -4,10 +4,9 @@ AbstractMesh + eval_shape)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
-from repro.distributed.compat import abstract_mesh
 from repro.distributed.sharding import DistConfig, param_specs
 from repro.models import init_params
 
@@ -43,7 +42,7 @@ def test_full_config_specs_divisible(arch, multi_pod):
     cfg = get_config(arch)  # FULL published config
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    mesh = abstract_mesh(shape, axes)
+    mesh = AbstractMesh(shape, axes)
     params = _abstract_params(cfg)
     specs = param_specs(params, mesh, DistConfig())
     _check(specs, params, mesh)
@@ -51,7 +50,7 @@ def test_full_config_specs_divisible(arch, multi_pod):
 
 def test_fsdp_over_pod_specs():
     cfg = get_config("kimi-k2-1t-a32b")
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     params = _abstract_params(cfg)
     specs = param_specs(params, mesh, DistConfig(fsdp_over_pod=True))
     _check(specs, params, mesh)
@@ -61,7 +60,7 @@ def test_big_weights_are_sharded():
     """No multi-GB leaf may end up fully replicated on the big archs."""
     for arch in ("internvl2-76b", "command-r-plus-104b", "kimi-k2-1t-a32b"):
         cfg = get_config(arch)
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         params = _abstract_params(cfg)
         specs = param_specs(params, mesh, DistConfig())
         flat_s = jax.tree_util.tree_leaves_with_path(

@@ -84,15 +84,14 @@ def test_compressed_psum_single_device():
     """shard_map int8 grad all-reduce on a trivial 1-device mesh equals
     identity within the quantization error bound."""
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compat import shard_map
     from repro.launch.mesh import make_local_mesh
     from repro.training.compression import compressed_psum
 
     mesh = make_local_mesh()
     g = jax.random.normal(jax.random.PRNGKey(0), (64, 64))
 
-    out = shard_map(lambda x: compressed_psum(x, "data"), mesh=mesh,
-                    in_specs=P(None, None), out_specs=P(None, None),
-                    check_vma=False)(g)
+    out = jax.shard_map(lambda x: compressed_psum(x, "data"), mesh=mesh,
+                        in_specs=P(None, None), out_specs=P(None, None),
+                        check_vma=False)(g)
     scale = float(jnp.max(jnp.abs(g))) / 127.0
     assert float(jnp.max(jnp.abs(out - g))) <= scale * 1.01
