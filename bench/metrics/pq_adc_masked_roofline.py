@@ -1,0 +1,17 @@
+"""pq_adc_masked_roofline: least time of every pq_adc_masked launch of
+the window (costs.py at its launch shape, peaks.json) over the device
+time of its compiled program (``jit_pq_adc_masked``)."""
+import costs
+
+
+def read(ctx):
+    tr, peak = ctx["trace"], ctx["peaks"]
+    launches = ctx["launches"]["pq_adc_masked"]
+    if tr is None or peak is None or not launches:
+        return None
+    t_dev = tr.module_s("jit_pq_adc_masked")
+    if t_dev <= 0:
+        return None
+    least = sum(costs.least_time(*costs.pq_adc_masked(*shape), peak)[0]
+                for shape in launches)
+    return 100.0 * least / t_dev
