@@ -93,8 +93,9 @@ object's id column bit-casts ``int32`` ids into the ``float32`` column
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -112,7 +113,7 @@ from repro.dataplane.plan import (
 from repro.dataplane.prefetch import PrefetchHandle
 from repro.dataplane.scan import ID_SENTINEL, INF, ScanStage, dedup_first
 from repro.dataplane.wave import WaveScheduler
-from repro.obs import get_metrics, get_tracer
+from repro.obs import get_metrics, get_tracer, host_span
 from repro.obs.metrics import COUNT_BUCKETS
 from repro.storage.resilience import FetchOutcome, codebook_keys, \
     replica_keys
@@ -348,199 +349,246 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
     compute = compute or ComputeModel()
     pg = pag.pg
     q_count = queries.shape[0]
-    q_dev = np.asarray(queries, np.float32)
-    if pad_rows > q_count > 0:  # pad with copies of a real query
-        q_dev = np.concatenate(
-            [q_dev, np.repeat(q_dev[:1], pad_rows - q_count, axis=0)])
-    A_dev, nbrs_dev, n_nodes, entry = pg.device_arrays()
-    res = greedy_search(A_dev, nbrs_dev, n_nodes, entry,
-                        jnp.asarray(q_dev), L=cfg.L, K=cfg.L)
-    path_all = np.asarray(res.path)[:q_count]
-    path_all_d2 = np.asarray(res.path_dists)[:q_count]
-    hops = np.asarray(res.n_hops)[:q_count]
-    beam_ids = np.asarray(res.ids)[:q_count]
-    beam_d2 = np.asarray(res.dists)[:q_count]
+    rows = pad_rows if pad_rows > q_count > 0 else q_count
+    with host_span("search", queries=q_count, rows=rows):
+        with host_span("graph") as sp:
+            q_dev = np.asarray(queries, np.float32)
+            if rows > q_count:  # pad with copies of a real query
+                q_dev = np.concatenate(
+                    [q_dev, np.repeat(q_dev[:1], rows - q_count, axis=0)])
+            A_dev, nbrs_dev, n_nodes, entry = pg.device_arrays()
+            q_dev = jnp.asarray(q_dev)
+            sp.set(h2d_bytes=A_dev.nbytes + nbrs_dev.nbytes + q_dev.nbytes)
+            res = greedy_search(A_dev, nbrs_dev, n_nodes, entry, q_dev,
+                                L=cfg.L, K=cfg.L)
+            path_all = np.asarray(res.path)[:q_count]
+            path_all_d2 = np.asarray(res.path_dists)[:q_count]
+            hops = np.asarray(res.n_hops)[:q_count]
+            beam_ids = np.asarray(res.ids)[:q_count]
+            beam_d2 = np.asarray(res.dists)[:q_count]
 
-    R_edges = pg.nbrs.shape[1]
-    traversal_s = [compute.search_hop(int(hops[qi]) * R_edges, x_dim)
-                   for qi in range(q_count)]
-    # APP replay: probe order per query (nonempty partitions only)
-    probes_all = probe_orders(pag, path_all, path_all_d2, hops,
-                              cfg.rho, cfg.n_probe_max)
+        with host_span("search.app_replay") as sp:
+            R_edges = pg.nbrs.shape[1]
+            traversal_s = [compute.search_hop(int(hops[qi]) * R_edges,
+                                              x_dim)
+                           for qi in range(q_count)]
+            # APP replay: probe order per query (nonempty partitions only)
+            probes_all = probe_orders(pag, path_all, path_all_d2, hops,
+                                      cfg.rho, cfg.n_probe_max)
+            sp.set(probes=sum(map(len, probes_all)))
 
-    if cfg.compression not in ("none", "pq"):
-        raise ValueError(f"unknown compression: {cfg.compression!r}")
-    pq = cfg.compression == "pq"
-    keyspace = KeySpace(prefix, n_shards, cfg.replicas)
+        if cfg.compression not in ("none", "pq"):
+            raise ValueError(f"unknown compression: {cfg.compression!r}")
+        pq = cfg.compression == "pq"
+        keyspace = KeySpace(prefix, n_shards, cfg.replicas)
 
-    tracer = get_tracer()
-    metrics = get_metrics()
-    rec = tracer.enabled   # keep the per-event schedule for the spans
-    timelines = [QueryTimeline(record=rec) for _ in range(q_count)]
-    degraded = [DegradedInfo(n_probes_wanted=len(probes_all[qi]))
-                for qi in range(q_count)]
-    for qi in range(q_count):
-        timelines[qi].add_compute(traversal_s[qi])
+        tracer = get_tracer()
+        metrics = get_metrics()
+        rec = tracer.enabled   # keep the per-event schedule for the spans
+        timelines = [QueryTimeline(record=rec) for _ in range(q_count)]
+        degraded = [DegradedInfo(n_probes_wanted=len(probes_all[qi]))
+                    for qi in range(q_count)]
+        for qi in range(q_count):
+            timelines[qi].add_compute(traversal_s[qi])
 
-    sched = WaveScheduler(store, cfg, timelines=timelines,
-                          degraded=degraded, compute=compute,
-                          dead_shard_fallback=dead_shard_fallback,
-                          record=rec, prefetched=prefetched)
-    scan = ScanStage(cfg.scan_block, pad_rows=pad_rows)
+        sched = WaveScheduler(store, cfg, timelines=timelines,
+                              degraded=degraded, compute=compute,
+                              dead_shard_fallback=dead_shard_fallback,
+                              record=rec, prefetched=prefetched)
+        scan = ScanStage(cfg.scan_block, pad_rows=pad_rows)
 
-    codebook, cb_lat = None, 0.0
-    if pq:
-        codebook, cb_lat, cb_oc = sched.load_codebook(keyspace,
-                                                      cache=cfg.cache)
-        if codebook is None:
-            # the compressed plane is down for this batch: every probe
-            # degrades like a lost partition (beam-only results)
-            for qi in range(q_count):
-                degraded[qi].n_probes_lost = len(probes_all[qi])
-                if cb_oc is not None:
-                    degraded[qi].add_outcome(cb_oc)
-            probes_all = [[] for _ in range(q_count)]
-        if cb_lat > 0:  # shared metadata fetch: charged to every query
-            for qi in range(q_count):
-                timelines[qi].issue_io(cb_lat, 0.0, label="codebook")
-
-    # probe wave: code objects under "pq" compression, else residuals.
-    # The ADC scan of a code object costs scan(cnt, M); exact scans
-    # cost scan(cnt, d).
-    probe_payload = PAYLOAD_CODE if pq else PAYLOAD_FLOAT
-    probe_cost = (lambda o: compute.scan(o.shape[0], o.shape[1])) if pq \
-        else (lambda o: compute.scan(o.shape[0], x_dim))
-    exact_cost = lambda o: compute.scan(o.shape[0], x_dim)  # noqa: E731
-    probe_kind = "adc" if pq else "scan"
-
-    fobjs: Dict[int, np.ndarray] = {}
-    refine_all: List[List[int]] = [[] for _ in range(q_count)]
-    handle: Optional[PrefetchHandle] = None
-    batch_span: Optional[float] = None
-
-    if cfg.engine == "batched":
-        plan = FetchPlan.build(probes_all, keyspace, probe_payload)
-        wave = sched.run_coalesced(plan, cache=cfg.cache)
-        sched.charge_queries(wave, probe_cost, kind=probe_kind)
-        objs = wave.objs
-        # batch event clock: a fetch issues when its FIRST prober's
-        # traversal retires; one coalesced scan per distinct partition
-        sched.charge_batch_codebook(cb_lat)
-        sched.charge_batch_probe(wave, traversal_s, x_dim, pq,
-                                 probe_kind)
+        codebook, cb_lat = None, 0.0
         if pq:
-            if codebook is not None and objs:
-                refine_all = scan.adc_select(codebook, queries,
-                                             probes_all, objs, pag,
-                                             cfg.rerank_k)
-            # stage boundary: the exact refine wave can only issue
-            # after the ADC pass over the code objects has retired
-            sched.barrier(cfg.mode)
-            t_prefetch = sched.bt.compute_s  # refine stage starts here
-            fplan = FetchPlan.build(refine_all, keyspace, PAYLOAD_FLOAT)
-            fwave = sched.run_coalesced(fplan, cache=None)
-            sched.charge_queries(fwave, exact_cost, kind="exact")
-            sched.charge_batch_refine(fwave, x_dim)
-            fobjs = fwave.objs
-        else:
-            t_prefetch = sched.bt.compute_s  # all traversals retired
-        if prefetch_probes is not None:
-            # overlap the NEXT micro-batch's probe wave with this
-            # batch's refine/scan tail on the event clock
-            handle = sched.prefetch(prefetch_probes, keyspace,
+            with _wave_span("codebook", store):
+                codebook, cb_lat, cb_oc = sched.load_codebook(
+                    keyspace, cache=cfg.cache)
+            if codebook is None:
+                # the compressed plane is down for this batch: every probe
+                # degrades like a lost partition (beam-only results)
+                for qi in range(q_count):
+                    degraded[qi].n_probes_lost = len(probes_all[qi])
+                    if cb_oc is not None:
+                        degraded[qi].add_outcome(cb_oc)
+                probes_all = [[] for _ in range(q_count)]
+            if cb_lat > 0:  # shared metadata fetch: charged to every query
+                for qi in range(q_count):
+                    timelines[qi].issue_io(cb_lat, 0.0, label="codebook")
+
+        # probe wave: code objects under "pq" compression, else residuals.
+        # The ADC scan of a code object costs scan(cnt, M); exact scans
+        # cost scan(cnt, d).
+        probe_payload = PAYLOAD_CODE if pq else PAYLOAD_FLOAT
+        probe_cost = (lambda o: compute.scan(o.shape[0], o.shape[1])) \
+            if pq else (lambda o: compute.scan(o.shape[0], x_dim))
+        exact_cost = lambda o: compute.scan(o.shape[0], x_dim)  # noqa: E731
+        probe_kind = "adc" if pq else "scan"
+
+        fobjs: Dict[int, np.ndarray] = {}
+        refine_all: List[List[int]] = [[] for _ in range(q_count)]
+        handle: Optional[PrefetchHandle] = None
+        batch_span: Optional[float] = None
+
+        def finish_batch(t_prefetch: float):
+            """The batched engine's last wave ends here: issue the NEXT
+            micro-batch's probe wave (overlapping this batch's refine/scan
+            tail on the event clock) and resolve the batch clock.
+            Returns (prefetch handle or None, batch makespan)."""
+            pf = None
+            if prefetch_probes is not None:
+                pf = sched.prefetch(prefetch_probes, keyspace,
                                     probe_payload, cache=cfg.cache,
                                     t_issue_s=t_prefetch)
-        batch_span = sched.finish_batch(cfg.mode)
-    elif cfg.engine == "per_query":
-        # seed data plane: blocking per-partition GETs, query by query
-        plan = FetchPlan.build(probes_all, keyspace, probe_payload)
-        objs, _ = sched.run_per_query(plan, cache=cfg.cache,
-                                      scan_cost=probe_cost,
-                                      kind=probe_kind)
-        if pq:
-            if codebook is not None and objs:
-                refine_all = scan.adc_select(codebook, queries,
-                                             probes_all, objs, pag,
-                                             cfg.rerank_k)
-            sched.barrier(cfg.mode)  # ADC retires before the refine wave
-            fplan = FetchPlan.build(refine_all, keyspace, PAYLOAD_FLOAT)
-            fobjs, _ = sched.run_per_query(fplan, cache=None,
-                                           scan_cost=exact_cost,
-                                           kind="exact")
-        batch_span = None  # serial stream: filled from latencies below
-    else:
-        raise ValueError(f"unknown engine: {cfg.engine!r}")
+            return pf, sched.finish_batch(cfg.mode)
 
-    if sched.resilient is not None:
-        n_open = sched.resilient.n_open_breakers()
-        for d in degraded:
-            d.breakers_open = n_open
+        if cfg.engine == "batched":
+            plan = _plan(probes_all, keyspace, probe_payload)
+            with _wave_span("probe", store):
+                wave = sched.run_coalesced(plan, cache=cfg.cache)
+                sched.charge_queries(wave, probe_cost, kind=probe_kind)
+                # batch event clock: a fetch issues when its FIRST
+                # prober's traversal retires; one coalesced scan per
+                # distinct partition
+                sched.charge_batch_codebook(cb_lat)
+                sched.charge_batch_probe(wave, traversal_s, x_dim, pq,
+                                         probe_kind)
+                if not pq:
+                    # every traversal retired: the batch's last wave
+                    handle, batch_span = finish_batch(sched.bt.compute_s)
+            objs = wave.objs
+            if pq:
+                if codebook is not None and objs:
+                    refine_all = scan.adc_select(codebook, queries,
+                                                 probes_all, objs, pag,
+                                                 cfg.rerank_k)
+                # stage boundary: the exact refine wave can only issue
+                # after the ADC pass over the code objects has retired
+                sched.barrier(cfg.mode)
+                t_prefetch = sched.bt.compute_s  # refine stage starts here
+                fplan = _plan(refine_all, keyspace, PAYLOAD_FLOAT)
+                with _wave_span("refine", store):
+                    fwave = sched.run_coalesced(fplan, cache=None)
+                    sched.charge_queries(fwave, exact_cost, kind="exact")
+                    sched.charge_batch_refine(fwave, x_dim)
+                    handle, batch_span = finish_batch(t_prefetch)
+                fobjs = fwave.objs
+        elif cfg.engine == "per_query":
+            # seed data plane: blocking per-partition GETs, query by query
+            plan = _plan(probes_all, keyspace, probe_payload)
+            with _wave_span("probe", store):
+                objs, _ = sched.run_per_query(plan, cache=cfg.cache,
+                                              scan_cost=probe_cost,
+                                              kind=probe_kind)
+            if pq:
+                if codebook is not None and objs:
+                    refine_all = scan.adc_select(codebook, queries,
+                                                 probes_all, objs, pag,
+                                                 cfg.rerank_k)
+                sched.barrier(cfg.mode)  # ADC retires before the refine
+                fplan = _plan(refine_all, keyspace, PAYLOAD_FLOAT)
+                with _wave_span("refine", store):
+                    fobjs, _ = sched.run_per_query(fplan, cache=None,
+                                                   scan_cost=exact_cost,
+                                                   kind="exact")
+            # serial stream: batch_span is filled from latencies below
+        else:
+            raise ValueError(f"unknown engine: {cfg.engine!r}")
 
-    # candidate pools: aggregation points on the beam (they are dataset
-    # points) + residuals of the available probed partitions, deduped by
-    # original id (redundant copies, Def 5). Under "pq" the exact pool
-    # draws from the refine wave's float objects.
-    pool_src = refine_all if pq else probes_all
-    pool_objs = fobjs if pq else objs
-    valid_beam = (beam_ids < pg.n_nodes) & (beam_d2 < INF)
-    beam_safe = np.minimum(beam_ids, pg.m_cap - 1)
-    pool_ids: List[np.ndarray] = []
-    pool_vecs: List[np.ndarray] = []
-    for qi in range(q_count):
-        nodes = beam_safe[qi][valid_beam[qi]]
-        ids_list = [pag.node_src[nodes].astype(np.int64)]
-        vec_list = [pg.A[nodes].astype(np.float32)]
-        for pid in pool_src[qi]:
-            obj = pool_objs.get(pid)
-            if obj is None:
-                continue
-            ids_list.append(_unpack_ids(obj[:, 0]))
-            vec_list.append(obj[:, 1:])
-        ids_cat = np.concatenate(ids_list)
-        keep = dedup_first(ids_cat)
-        pool_ids.append(ids_cat[keep])
-        pool_vecs.append(np.concatenate(vec_list)[keep])
+        if sched.resilient is not None:
+            n_open = sched.resilient.n_open_breakers()
+            for d in degraded:
+                d.breakers_open = n_open
 
-    out_ids, out_d2 = scan.topk(queries.astype(np.float32), pool_ids,
-                                pool_vecs, cfg.k)
+        # candidate pools: aggregation points on the beam (they are
+        # dataset points) + residuals of the available probed partitions,
+        # deduped by original id (redundant copies, Def 5). Under "pq" the
+        # exact pool draws from the refine wave's float objects.
+        with host_span("search.pool") as sp:
+            pool_src = refine_all if pq else probes_all
+            pool_objs = fobjs if pq else objs
+            valid_beam = (beam_ids < pg.n_nodes) & (beam_d2 < INF)
+            beam_safe = np.minimum(beam_ids, pg.m_cap - 1)
+            pool_ids: List[np.ndarray] = []
+            pool_vecs: List[np.ndarray] = []
+            for qi in range(q_count):
+                nodes = beam_safe[qi][valid_beam[qi]]
+                ids_list = [pag.node_src[nodes].astype(np.int64)]
+                vec_list = [pg.A[nodes].astype(np.float32)]
+                for pid in pool_src[qi]:
+                    obj = pool_objs.get(pid)
+                    if obj is None:
+                        continue
+                    ids_list.append(_unpack_ids(obj[:, 0]))
+                    vec_list.append(obj[:, 1:])
+                ids_cat = np.concatenate(ids_list)
+                keep = dedup_first(ids_cat)
+                pool_ids.append(ids_cat[keep])
+                pool_vecs.append(np.concatenate(vec_list)[keep])
+            sp.set(candidates=sum(map(len, pool_ids)))
 
-    stats = SearchStats([], [], [],
-                        n_distinct_fetches=sched.n_store,
-                        degraded=degraded,
-                        n_prefetch_hits=sched.n_prefetch_hits,
-                        prefetch=handle)
-    if cfg.cache is not None:
-        stats.cache_hit_rate = cfg.cache.hit_rate
-        stats.cache_bytes_evicted = cfg.cache.bytes_evicted
-    for qi in range(q_count):
-        tl = timelines[qi]
-        lat_q = tl.finish_async() if cfg.mode == "async" \
-            else tl.finish_sync()
-        stats.latencies_s.append(lat_q)
-        stats.n_probes.append(
-            sum(1 for pid in probes_all[qi] if pid in objs))
-        stats.n_hops.append(int(hops[qi]))
-    stats.batch_span_s = batch_span if batch_span is not None \
-        else float(np.sum(stats.latencies_s))
-    if metrics.enabled:
-        metrics.inc("search.batches")
-        metrics.inc("search.queries", q_count)
-        for qi in range(q_count):
-            metrics.observe("search.latency_s", stats.latencies_s[qi])
-            metrics.observe("search.pool_size", len(pool_ids[qi]),
-                            bounds=COUNT_BUCKETS)
-            metrics.observe("search.retries_per_query",
-                            degraded[qi].retries, bounds=COUNT_BUCKETS)
-        if stats.n_prefetch_hits:
-            metrics.inc("search.prefetch_hits", stats.n_prefetch_hits)
-        metrics.observe("search.batch_span_s", stats.batch_span_s)
-    if rec:
-        from repro.obs.trace import emit_search_spans
-        stats.trace_group = emit_search_spans(
-            tracer,
-            batch_events=(sched.bt.events
-                          if cfg.engine == "batched" else None),
-            batch_span_s=stats.batch_span_s, timelines=timelines,
-            latencies_s=stats.latencies_s, engine=cfg.engine, pq=pq,
-            n_probes=stats.n_probes, t0_s=trace_t0_s) or ""
-    return out_ids, out_d2, stats
+        out_ids, out_d2 = scan.topk(queries.astype(np.float32), pool_ids,
+                                    pool_vecs, cfg.k)
+
+        with host_span("search.stats"):
+            stats = SearchStats([], [], [],
+                                n_distinct_fetches=sched.n_store,
+                                degraded=degraded,
+                                n_prefetch_hits=sched.n_prefetch_hits,
+                                prefetch=handle)
+            if cfg.cache is not None:
+                stats.cache_hit_rate = cfg.cache.hit_rate
+                stats.cache_bytes_evicted = cfg.cache.bytes_evicted
+            for qi in range(q_count):
+                tl = timelines[qi]
+                lat_q = tl.finish_async() if cfg.mode == "async" \
+                    else tl.finish_sync()
+                stats.latencies_s.append(lat_q)
+                stats.n_probes.append(
+                    sum(1 for pid in probes_all[qi] if pid in objs))
+                stats.n_hops.append(int(hops[qi]))
+            stats.batch_span_s = batch_span if batch_span is not None \
+                else float(np.sum(stats.latencies_s))
+            if metrics.enabled:
+                metrics.inc("search.batches")
+                metrics.inc("search.queries", q_count)
+                for qi in range(q_count):
+                    metrics.observe("search.latency_s",
+                                    stats.latencies_s[qi])
+                    metrics.observe("search.pool_size", len(pool_ids[qi]),
+                                    bounds=COUNT_BUCKETS)
+                    metrics.observe("search.retries_per_query",
+                                    degraded[qi].retries,
+                                    bounds=COUNT_BUCKETS)
+                if stats.n_prefetch_hits:
+                    metrics.inc("search.prefetch_hits",
+                                stats.n_prefetch_hits)
+                metrics.observe("search.batch_span_s", stats.batch_span_s)
+            if rec:
+                from repro.obs.trace import emit_search_spans
+                stats.trace_group = emit_search_spans(
+                    tracer,
+                    batch_events=(sched.bt.events
+                                  if cfg.engine == "batched" else None),
+                    batch_span_s=stats.batch_span_s, timelines=timelines,
+                    latencies_s=stats.latencies_s, engine=cfg.engine,
+                    pq=pq, n_probes=stats.n_probes,
+                    t0_s=trace_t0_s) or ""
+            return out_ids, out_d2, stats
+
+
+def _plan(probes_all: List[List[int]], keyspace: KeySpace,
+          payload: str) -> FetchPlan:
+    """``FetchPlan.build`` in its ``anns/plan.build`` span."""
+    with host_span("plan.build") as sp:
+        plan = FetchPlan.build(probes_all, keyspace, payload)
+        sp.set(keys=len(plan.order))
+    return plan
+
+
+@contextlib.contextmanager
+def _wave_span(name: str, store: ObjectStore) -> Iterator[None]:
+    """``anns/wave.<name>``: a storage wave on the simulated store and
+    its clock charges, with the GETs and bytes the store served in it."""
+    gets, nbytes = store.n_gets, store.bytes_fetched
+    with host_span("wave." + name) as sp:
+        yield
+        sp.set(gets=store.n_gets - gets, bytes=store.bytes_fetched - nbytes)

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core.graph_search import greedy_search
 from repro.dataplane.plan import probe_orders
+from repro.obs import host_span
 
 
 @dataclasses.dataclass
@@ -64,10 +65,12 @@ def predict_probes(pag, queries: np.ndarray, cfg) -> list:
     in-memory graph phase + APP replay that ``search_pag`` itself runs
     (same ``probe_orders`` code path ⇒ the prediction IS the next
     batch's probe list, partition for partition)."""
-    pg = pag.pg
-    A_dev, nbrs_dev, n_nodes, entry = pg.device_arrays()
-    res = greedy_search(A_dev, nbrs_dev, n_nodes, entry,
-                        jnp.asarray(queries), L=cfg.L, K=cfg.L)
-    return probe_orders(pag, np.asarray(res.path),
-                        np.asarray(res.path_dists),
-                        np.asarray(res.n_hops), cfg.rho, cfg.n_probe_max)
+    with host_span("graph") as sp:
+        A_dev, nbrs_dev, n_nodes, entry = pag.pg.device_arrays()
+        q_dev = jnp.asarray(queries)
+        sp.set(h2d_bytes=A_dev.nbytes + nbrs_dev.nbytes + q_dev.nbytes)
+        res = greedy_search(A_dev, nbrs_dev, n_nodes, entry, q_dev,
+                            L=cfg.L, K=cfg.L)
+        path, path_d2 = np.asarray(res.path), np.asarray(res.path_dists)
+        hops = np.asarray(res.n_hops)
+    return probe_orders(pag, path, path_d2, hops, cfg.rho, cfg.n_probe_max)

@@ -3,21 +3,23 @@
 ``ScanStage`` wraps the two masked ragged-pool launches — ``l2_topk``
 (exact distance/top-k over the pooled candidates) and ``pq_adc`` (ADC
 scoring of pooled PQ codes + cover-aware refine-partition selection) —
-behind one object that owns padding, id bookkeeping, host wall-clock
-tracing of the launches, and the dedup rule for redundant copies
-(Def 5). Both engines and the benchmarks go through this stage; nothing
-else in the tree calls ``kernels.ops`` for the query path.
+behind one object that owns padding, id bookkeeping, and the dedup rule
+for redundant copies (Def 5). Both engines and the benchmarks go through
+this stage; nothing else in the tree calls ``kernels.ops`` for the query
+path. Each step is a host span (``anns/scan.*``); a launch span covers
+the host->device copy, the kernel and the pull of its result, with the
+bytes copied (``h2d_bytes``), the launch's slots (rows x pool width) and
+the slots that hold a real candidate (``filled``).
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
-from repro.obs import get_metrics, get_tracer
+from repro.obs import host_span
 
 INF = np.float32(3.4e38)
 ID_SENTINEL = 2 ** 62   # invalid-id marker used during dedup
@@ -62,28 +64,25 @@ class ScanStage:
             return (np.full((q_count, k), -1, np.int64),
                     np.full((q_count, k), INF, np.float32))
         rows, width = self._shape(q_count, c_max)
-        q_pad = np.zeros((rows, d), np.float32)
-        q_pad[:q_count] = queries
-        ids_pad = np.full((rows, width), -1, np.int32)
-        vecs_pad = np.zeros((rows, width, d), np.float32)
-        for qi in range(q_count):
-            n = len(pool_ids[qi])
-            if n:
-                ids_pad[qi, :n] = pool_ids[qi]
-                vecs_pad[qi, :n] = pool_vecs[qi]
-        tracer = get_tracer()
-        t0 = time.perf_counter() if tracer.enabled else 0.0
-        d2, ids = ops.l2_topk_masked(
-            jnp.asarray(q_pad), jnp.asarray(vecs_pad),
-            jnp.asarray(ids_pad), k=k, block_c=self.scan_block)
-        out = (np.asarray(ids)[:q_count].astype(np.int64),
-               np.asarray(d2)[:q_count])
-        if tracer.enabled:  # np.asarray forced the async dispatch above
-            dt = time.perf_counter() - t0
-            tracer.wall_span("pallas_launch l2_topk", dt,
-                             {"queries": q_count, "c_max": c_max, "k": k})
-            get_metrics().observe("kernels.launch_s", dt)
-        return out
+        with host_span("scan.topk_pad"):
+            q_pad = np.zeros((rows, d), np.float32)
+            q_pad[:q_count] = queries
+            ids_pad = np.full((rows, width), -1, np.int32)
+            vecs_pad = np.zeros((rows, width, d), np.float32)
+            for qi in range(q_count):
+                n = len(pool_ids[qi])
+                if n:
+                    ids_pad[qi, :n] = pool_ids[qi]
+                    vecs_pad[qi, :n] = pool_vecs[qi]
+        with host_span("scan.topk_launch",
+                       h2d_bytes=q_pad.nbytes + vecs_pad.nbytes
+                       + ids_pad.nbytes, slots=rows * width,
+                       filled=sum(map(len, pool_ids))):
+            d2, ids = ops.l2_topk_masked(
+                jnp.asarray(q_pad), jnp.asarray(vecs_pad),
+                jnp.asarray(ids_pad), k=k, block_c=self.scan_block)
+            return (np.asarray(ids)[:q_count].astype(np.int64),
+                    np.asarray(d2)[:q_count])
 
     # ------------------------------------------------------------ ADC pass
     def adc_select(self, codebook, queries: np.ndarray,
@@ -106,75 +105,74 @@ class ScanStage:
         cand_codes: List[np.ndarray] = []
         cand_ids: List[np.ndarray] = []
         id_pids: List[Dict[int, List[int]]] = []  # id -> probed pids
-        for qi in range(q_count):
-            ids_l, pids_l, codes_l = [], [], []
-            for pid in probes_all[qi]:
-                codes = objs.get(pid)
-                if codes is None:
-                    continue
-                cnt = codes.shape[0]
-                ids_l.append(pag.plist[pid, :cnt].astype(np.int64))
-                pids_l.append(np.full(cnt, pid, np.int32))
-                codes_l.append(codes)
-            if ids_l:
-                ids_c = np.concatenate(ids_l)
-                pids_c = np.concatenate(pids_l)
-                keep = dedup_first(ids_c)  # redundant copies score once
-                cand_pids.append(pids_c[keep])
-                cand_codes.append(np.concatenate(codes_l)[keep])
-                cand_ids.append(ids_c[keep])
-                by_id: Dict[int, List[int]] = {}
-                for i, cid in zip(pids_c, ids_c):
-                    by_id.setdefault(int(cid), []).append(int(i))
-                id_pids.append(by_id)
-            else:
-                cand_pids.append(np.zeros(0, np.int32))
-                cand_codes.append(np.zeros((0, codebook.M), np.uint8))
-                cand_ids.append(np.zeros(0, np.int64))
-                id_pids.append({})
+        with host_span("scan.adc_pool"):
+            for qi in range(q_count):
+                ids_l, pids_l, codes_l = [], [], []
+                for pid in probes_all[qi]:
+                    codes = objs.get(pid)
+                    if codes is None:
+                        continue
+                    cnt = codes.shape[0]
+                    ids_l.append(pag.plist[pid, :cnt].astype(np.int64))
+                    pids_l.append(np.full(cnt, pid, np.int32))
+                    codes_l.append(codes)
+                if ids_l:
+                    ids_c = np.concatenate(ids_l)
+                    pids_c = np.concatenate(pids_l)
+                    keep = dedup_first(ids_c)  # redundant copies score once
+                    cand_pids.append(pids_c[keep])
+                    cand_codes.append(np.concatenate(codes_l)[keep])
+                    cand_ids.append(ids_c[keep])
+                    by_id: Dict[int, List[int]] = {}
+                    for i, cid in zip(pids_c, ids_c):
+                        by_id.setdefault(int(cid), []).append(int(i))
+                    id_pids.append(by_id)
+                else:
+                    cand_pids.append(np.zeros(0, np.int32))
+                    cand_codes.append(np.zeros((0, codebook.M), np.uint8))
+                    cand_ids.append(np.zeros(0, np.int64))
+                    id_pids.append({})
 
         c_max = max((len(p) for p in cand_pids), default=0)
         if c_max == 0:
             return [[] for _ in range(q_count)]
         m = codebook.M
         rows, width = self._shape(q_count, c_max)
-        codes_pad = np.zeros((rows, width, m), np.uint8)
-        pos_pad = np.full((rows, width), -1, np.int32)
-        for qi in range(q_count):
-            n = len(cand_pids[qi])
-            if n:
-                codes_pad[qi, :n] = cand_codes[qi]
-                pos_pad[qi, :n] = np.arange(n, dtype=np.int32)
-        luts = np.zeros((rows, m, 256), np.float32)
-        luts[:q_count] = adc_lut_batch(codebook,
-                                       np.asarray(queries, np.float32))
-        tracer = get_tracer()
-        t0 = time.perf_counter() if tracer.enabled else 0.0
-        _, pos = ops.pq_adc_masked(
-            jnp.asarray(luts), jnp.asarray(codes_pad),
-            jnp.asarray(pos_pad), k=rerank_k, block_c=self.scan_block)
-        pos = np.asarray(pos)[:q_count]
-        if tracer.enabled:  # np.asarray forced the async dispatch above
-            dt = time.perf_counter() - t0
-            tracer.wall_span("pallas_launch pq_adc", dt,
-                             {"queries": q_count, "c_max": c_max, "M": m,
-                              "rerank_k": rerank_k})
-            get_metrics().observe("kernels.launch_s", dt)
+        with host_span("scan.adc_lut"):
+            codes_pad = np.zeros((rows, width, m), np.uint8)
+            pos_pad = np.full((rows, width), -1, np.int32)
+            for qi in range(q_count):
+                n = len(cand_pids[qi])
+                if n:
+                    codes_pad[qi, :n] = cand_codes[qi]
+                    pos_pad[qi, :n] = np.arange(n, dtype=np.int32)
+            luts = np.zeros((rows, m, 256), np.float32)
+            luts[:q_count] = adc_lut_batch(codebook,
+                                           np.asarray(queries, np.float32))
+        with host_span("scan.adc_launch",
+                       h2d_bytes=luts.nbytes + codes_pad.nbytes
+                       + pos_pad.nbytes, slots=rows * width,
+                       filled=sum(map(len, cand_pids))):
+            _, pos = ops.pq_adc_masked(
+                jnp.asarray(luts), jnp.asarray(codes_pad),
+                jnp.asarray(pos_pad), k=rerank_k, block_c=self.scan_block)
+            pos = np.asarray(pos)[:q_count]
 
         refine_all: List[List[int]] = []
-        for qi in range(q_count):
-            chosen: List[int] = []
-            chosen_set: set = set()
-            for p in pos[qi]:
-                if p < 0:
-                    continue
-                copies = id_pids[qi].get(int(cand_ids[qi][p]))
-                if copies is None:  # defensive: scored row has copies
-                    copies = [int(cand_pids[qi][p])]
-                if chosen_set.intersection(copies):
-                    continue  # a selected partition already holds a copy
-                pid = int(cand_pids[qi][p])
-                chosen.append(pid)
-                chosen_set.add(pid)
-            refine_all.append(chosen)
+        with host_span("scan.cover_select"):
+            for qi in range(q_count):
+                chosen: List[int] = []
+                chosen_set: set = set()
+                for p in pos[qi]:
+                    if p < 0:
+                        continue
+                    copies = id_pids[qi].get(int(cand_ids[qi][p]))
+                    if copies is None:  # defensive: scored row has copies
+                        copies = [int(cand_pids[qi][p])]
+                    if chosen_set.intersection(copies):
+                        continue  # a selected partition holds a copy
+                    pid = int(cand_pids[qi][p])
+                    chosen.append(pid)
+                    chosen_set.add(pid)
+                refine_all.append(chosen)
         return refine_all

@@ -4,9 +4,11 @@ Chrome-trace / Perfetto ``trace.json`` exporter.
 Spans live on named *tracks* (one Perfetto thread row each): the batch
 event clock gets one track per flushed batch (``batch0``, ``batch1``,
 ...), each traced query gets a child track (``batch0/q3``), the serving
-front-end gets ``frontend``, and host-side Pallas kernel launches go on
-a wall-clock track in their own process group (the two clocks must not
-share a timeline). Three span shapes:
+front-end gets ``frontend``, and the served path's host spans
+(``repro.obs.host_span``: ``anns/search``, ``anns/scan.topk_launch``, ...)
+go on the ``host`` track of a wall-clock process group, at their real
+starts relative to the tracer's creation, nested as they ran (the two
+clocks must not share a timeline). Three span shapes:
 
 * ``span``    — a complete slice (``ph: "X"``). Slices on one track nest
   by time containment, which is how the hierarchy renders: the root
@@ -31,9 +33,10 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
-WALL_GROUP = "host-wall"      # wall-clock process group (kernel launches)
+WALL_GROUP = "host-wall"      # wall-clock process group (host spans)
 EVENT_GROUP = "event-clock"   # simulated-time process group
 
 
@@ -71,7 +74,7 @@ class Tracer:
         self.n_dropped = 0
         self._tracks: Dict[str, int] = {}   # name -> creation order
         self._groups: Dict[str, int] = {}   # group counters (next_name)
-        self._wall_t = 0.0                  # cursor of the wall track
+        self.t0_wall_s = time.perf_counter()  # origin of the wall group
         self._flow_id = 0                   # flow-arrow id counter
 
     # ------------------------------------------------------------- tracks
@@ -135,15 +138,6 @@ class Tracer:
                                "s", EVENT_GROUP, None, self._flow_id))
         self.spans.append(Span(to_track, name, t_to_s, 0.0, "flow",
                                "f", EVENT_GROUP, None, self._flow_id))
-
-    def wall_span(self, name: str, dur_s: float,
-                  args: Optional[dict] = None,
-                  track: str = "pallas") -> None:
-        """Host wall-clock span (kernel launches); sequential cursor —
-        the wall clock and the event clock never share a timeline."""
-        self._add(Span(track, name, self._wall_t, dur_s, "kernel", "X",
-                       WALL_GROUP, args))
-        self._wall_t += dur_s
 
     # ------------------------------------------------------------- export
     def to_chrome(self) -> dict:
@@ -231,9 +225,6 @@ class NoopTracer(Tracer):
         pass
 
     def flow(self, *a, **k):
-        pass
-
-    def wall_span(self, *a, **k):
         pass
 
 

@@ -15,7 +15,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import decode_step, prefill
-from repro.obs import get_metrics, get_tracer
+from repro.obs import get_metrics, get_tracer, host_span
 from repro.obs.metrics import COUNT_BUCKETS
 
 
@@ -119,10 +119,10 @@ class AnnsFrontend:
         self.predictor = predictor
         self.results: Dict[int, Tuple[np.ndarray, np.ndarray, float]] = {}
         self.degraded: Dict[int, object] = {}   # ticket -> DegradedInfo
-        self.queue_wait_s: Dict[int, float] = {}  # ticket -> wall wait
         self.n_prefetch_hits = 0    # probes served by prefetch waves
         self._pending: List[Tuple[int, np.ndarray, float]] = []
         self._next_ticket = 0
+        self._n_flushes = 0     # micro-batches flushed (the span's batch)
         self._clock_s = 0.0     # event-clock cursor: flushes lay end-to-end
         self._handle = None     # in-flight PrefetchHandle (absolute clock)
 
@@ -149,63 +149,70 @@ class AnnsFrontend:
         tracer, metrics = get_tracer(), get_metrics()
         now = time.perf_counter()
         tickets = [t for t, _, _ in chunk]
-        batch = np.stack([q for _, q, _ in chunk])
         waits = [now - t0 for _, _, t0 in chunk]
-        t0 = self._clock_s
-        # a short chunk launches at the full batch's shapes (no recompile)
-        kw = {"pad_rows": self.max_batch}
-        if self._handle is not None:
-            # the previous chunk prefetched this chunk's probe wave;
-            # pay only each object's residual latency past our start
-            kw["prefetched"] = self._handle.residuals(t0)
-            self._handle = None
-        if self.prefetch and self.predictor is not None and self._pending:
-            nxt = np.stack([q for _, q, _ in
-                            self._pending[:self.max_batch]])
-            kw["prefetch_probes"] = self.predictor(nxt)
-        if tracer.enabled:
-            # batch spans share the frontend clock (flow arrows point
-            # forward in time)
-            kw["trace_t0_s"] = t0
-        ids, d2, stats = self.serving.search(batch, self.cfg,
-                                             compute=self.compute, **kw)
-        if stats.prefetch is not None:
-            # handle times are relative to this chunk's start; pin them
-            # to the frontend clock for the next chunk's residuals
-            for key in stats.prefetch.ready_rel_s:
-                stats.prefetch.ready_rel_s[key] += t0
-            stats.prefetch.issued_rel_s += t0
-            self._handle = stats.prefetch
-        self.n_prefetch_hits += stats.n_prefetch_hits
-        for row, ticket in enumerate(tickets):
-            self.results[ticket] = (ids[row], d2[row],
-                                    stats.latencies_s[row])
-            self.queue_wait_s[ticket] = waits[row]
-            if stats.degraded:
-                self.degraded[ticket] = stats.degraded[row]
-        self.last_stats = stats
-        if metrics.enabled:
-            metrics.inc("frontend.flushes")
-            metrics.observe("frontend.batch_size", len(tickets),
-                            bounds=COUNT_BUCKETS)
-            for w in waits:
-                metrics.observe("frontend.queue_wait_s", w)
-        if tracer.enabled:
-            # flushes lay end-to-end on the frontend's event clock;
-            # ticket slices stack (aspan) since they start together
-            tracer.span("frontend", f"flush[{len(tickets)}q]", t0,
-                        stats.batch_span_s, cat="flush",
-                        args={"tickets": len(tickets)})
+        batch_no = self._n_flushes
+        self._n_flushes += 1
+        # the batch's root span: every span of its requests nests in it
+        with host_span("frontend.flush", batch=batch_no,
+                       tickets=len(tickets), first_ticket=tickets[0],
+                       queue_wait_ns_sum=round(sum(waits) * 1e9)):
+            batch = np.stack([q for _, q, _ in chunk])
+            t0 = self._clock_s
+            # a short chunk launches at the full batch's shapes (no
+            # recompile)
+            kw = {"pad_rows": self.max_batch}
+            if self._handle is not None:
+                # the previous chunk prefetched this chunk's probe wave;
+                # pay only each object's residual latency past our start
+                kw["prefetched"] = self._handle.residuals(t0)
+                self._handle = None
+            if (self.prefetch and self.predictor is not None
+                    and self._pending):
+                nxt = np.stack([q for _, q, _ in
+                                self._pending[:self.max_batch]])
+                kw["prefetch_probes"] = self.predictor(nxt)
+            if tracer.enabled:
+                # batch spans share the frontend clock (flow arrows point
+                # forward in time)
+                kw["trace_t0_s"] = t0
+            ids, d2, stats = self.serving.search(batch, self.cfg,
+                                                 compute=self.compute, **kw)
+            if stats.prefetch is not None:
+                # handle times are relative to this chunk's start; pin
+                # them to the frontend clock for the next chunk's residuals
+                for key in stats.prefetch.ready_rel_s:
+                    stats.prefetch.ready_rel_s[key] += t0
+                stats.prefetch.issued_rel_s += t0
+                self._handle = stats.prefetch
+            self.n_prefetch_hits += stats.n_prefetch_hits
             for row, ticket in enumerate(tickets):
-                tracer.aspan("frontend", f"t{ticket}", t0,
-                             stats.latencies_s[row], cat="ticket",
-                             args={"queue_wait_s": waits[row]})
-                if stats.trace_group:
-                    # ticket -> its per-query child track
-                    tracer.flow("frontend", t0,
-                                f"{stats.trace_group}/q{row}", t0,
-                                name=f"t{ticket}")
-        self._clock_s += stats.batch_span_s
+                self.results[ticket] = (ids[row], d2[row],
+                                        stats.latencies_s[row])
+                if stats.degraded:
+                    self.degraded[ticket] = stats.degraded[row]
+            self.last_stats = stats
+            if metrics.enabled:
+                metrics.inc("frontend.flushes")
+                metrics.observe("frontend.batch_size", len(tickets),
+                                bounds=COUNT_BUCKETS)
+                for w in waits:
+                    metrics.observe("frontend.queue_wait_s", w)
+            if tracer.enabled:
+                # flushes lay end-to-end on the frontend's event clock;
+                # ticket slices stack (aspan) since they start together
+                tracer.span("frontend", f"flush[{len(tickets)}q]", t0,
+                            stats.batch_span_s, cat="flush",
+                            args={"tickets": len(tickets)})
+                for row, ticket in enumerate(tickets):
+                    tracer.aspan("frontend", f"t{ticket}", t0,
+                                 stats.latencies_s[row], cat="ticket",
+                                 args={"queue_wait_s": waits[row]})
+                    if stats.trace_group:
+                        # ticket -> its per-query child track
+                        tracer.flow("frontend", t0,
+                                    f"{stats.trace_group}/q{row}", t0,
+                                    name=f"t{ticket}")
+            self._clock_s += stats.batch_span_s
 
     def degraded_summary(self):
         """Batch-level ``DegradedInfo`` aggregated over every flushed

@@ -140,6 +140,57 @@ def test_trace_json_is_perfetto_loadable(built_pag, small_ds, tmp_path):
     names = {e["args"]["name"] for e in evs
              if e["ph"] == "M" and e["name"] == "process_name"}
     assert names == {"event-clock", "host-wall"}
+    # the host-wall group holds the search's host spans at their real
+    # starts: nested as they ran, the stages starting in pipeline order
+    wall = [s for s in tr.spans if s.group == "host-wall"]
+    by_name = {s.name: s for s in wall}
+    outer, launch = by_name["anns/search"], by_name["anns/scan.topk_launch"]
+    assert outer.t0_s <= launch.t0_s and launch.t1_s <= outer.t1_s
+    stages = ["anns/search", "anns/graph", "anns/search.app_replay",
+              "anns/plan.build", "anns/wave.probe", "anns/search.pool",
+              "anns/scan.topk_pad", "anns/scan.topk_launch",
+              "anns/search.stats"]
+    starts = [by_name[n].t0_s for n in stages]
+    assert starts == sorted(starts) and starts[0] >= 0.0
+    for s in wall:
+        assert outer.t0_s <= s.t0_s and s.t1_s <= outer.t1_s
+
+
+def test_host_span_goes_to_an_installed_tracer_only():
+    from repro.obs import host_span
+    with host_span("probe", keys=3) as sp:   # no tracer: profiler only
+        sp.set(gets=2)
+    assert sp.stats == {"keys": 3, "gets": 2}
+    tr = Tracer()
+    with observe(tracer=tr):
+        with host_span("outer"):
+            with host_span("inner", h2d_bytes=4096) as sp:
+                sp.set(batch=1)
+    inner, outer = tr.spans               # recorded as each one closes
+    assert (inner.name, outer.name) == ("anns/inner", "anns/outer")
+    assert inner.group == outer.group == "host-wall"
+    assert inner.args == {"h2d_bytes": 4096, "batch": 1}
+    assert 0.0 <= outer.t0_s <= inner.t0_s
+    assert inner.t1_s <= outer.t1_s
+    assert not NOOP_TRACER.spans
+
+
+@pytest.mark.parametrize("compression", ["none", "pq"])
+def test_profiler_session_leaves_results_identical(built_pag, small_ds,
+                                                   tmp_path, compression):
+    """The host spans are always emitted; an active profiler session
+    records them and changes no id, distance or modelled latency."""
+    import jax
+    kw = {"compression": compression}
+    ids0, d20, st0 = _search(built_pag, small_ds,
+                             _mk_store(built_pag, small_ds, **kw), **kw)
+    with jax.profiler.trace(str(tmp_path)):
+        ids1, d21, st1 = _search(built_pag, small_ds,
+                                 _mk_store(built_pag, small_ds, **kw), **kw)
+    np.testing.assert_array_equal(ids0, ids1)
+    np.testing.assert_array_equal(d20, d21)
+    assert st0.latencies_s == st1.latencies_s
+    assert list(tmp_path.rglob("*.xplane.pb"))
 
 
 def test_pq_trace_has_stage_spans(built_pag, small_ds):
@@ -288,12 +339,18 @@ def test_frontend_queue_wait_and_spans(built_pag, small_ds):
         fe.flush()
     for t in tickets:
         assert t in fe.results
-        assert fe.queue_wait_s[t] >= 0.0
     flushes = [s for s in tr.spans if s.cat == "flush"]
     assert len(flushes) == 1
     assert flushes[0].dur_s == pytest.approx(
         fe.last_stats.batch_span_s)
-    assert len([s for s in tr.spans if s.cat == "ticket"]) == 6
+    ticket_spans = [s for s in tr.spans if s.cat == "ticket"]
+    assert len(ticket_spans) == 6
+    assert all(s.args["queue_wait_s"] >= 0.0 for s in ticket_spans)
+    # the flush's host span carries the chunk's summed queue wait
+    (flush,) = [s for s in tr.spans if s.name == "anns/frontend.flush"]
+    assert flush.args["tickets"] == 6 and flush.args["first_ticket"] == 0
+    assert flush.args["queue_wait_ns_sum"] == pytest.approx(
+        sum(s.args["queue_wait_s"] for s in ticket_spans) * 1e9, abs=1.0)
     snap = mx.snapshot()
     assert snap["frontend.flushes"] == 1.0
     assert snap["frontend.batch_size.count"] == 1.0
