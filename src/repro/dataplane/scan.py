@@ -9,10 +9,12 @@ this stage; nothing else in the tree calls ``kernels.ops`` for the query
 path. Each step is a host span (``anns/scan.*``); a launch span covers
 the host->device copy, the kernel and the pull of its result, with the
 bytes copied (``h2d_bytes``), the launch's slots (rows x pool width) and
-the slots that hold a real candidate (``filled``).
+the slots that hold a real candidate (``filled``); the ADC pool span
+counts the rows pooled (``rows``) and those kept after dedup (``kept``).
 """
 from __future__ import annotations
 
+import itertools
 from typing import Dict, List, Tuple
 
 import jax.numpy as jnp
@@ -34,6 +36,84 @@ def dedup_first(ids: np.ndarray) -> np.ndarray:
     mask[first] = True
     mask &= ids < ID_SENTINEL
     return mask
+
+
+class _AdcPool:
+    """One batch's ADC candidate pool in flat arrays. Rows run query by
+    query, each query's probes in its order, each fetched object row by
+    row: the order of a per-query concatenation. A query keeps the first
+    row of each id (``dedup_first``'s rule, ids < 0 dropped); every
+    pooled row stays in ``keys``/``key_pids``, sorted by (query, id), so
+    a candidate's copies are one slice of them."""
+
+    def __init__(self, probes_all: List[List[int]],
+                 objs: Dict[int, np.ndarray], plist: np.ndarray, m: int):
+        q_count = len(probes_all)
+        n_probes = np.fromiter(map(len, probes_all), np.int64, q_count)
+        probe_pid = np.fromiter(itertools.chain.from_iterable(probes_all),
+                                np.int64, int(n_probes.sum()))
+        probe_q = np.repeat(np.arange(q_count), n_probes)
+        # each distinct object once: a missing one brings no rows
+        uniq, probe_obj = np.unique(probe_pid, return_inverse=True)
+        got = [objs.get(pid) for pid in uniq.tolist()]
+        obj_rows = np.array([0 if o is None else o.shape[0] for o in got],
+                            np.int64)
+        present = [o for o in got if o is not None]
+        all_codes = (np.concatenate(present) if present
+                     else np.zeros((0, m), np.uint8))
+        # expand each probe to its object's rows
+        cnt = obj_rows[probe_obj]
+        self.n_rows = int(cnt.sum())
+        row_probe = np.repeat(np.arange(len(cnt)), cnt)
+        within = np.arange(self.n_rows) - np.repeat(np.cumsum(cnt) - cnt, cnt)
+        row_q = probe_q[row_probe]
+        row_pid = probe_pid[row_probe]
+        row_id = plist[row_pid, within].astype(np.int64)
+        row_code = (np.cumsum(obj_rows) - obj_rows)[probe_obj][row_probe] \
+            + within
+        # dedup per query: one stable sort on (query, id)
+        valid = np.flatnonzero(row_id >= 0)
+        self.span = int(row_id.max(initial=-1)) + 1
+        key = row_q[valid] * self.span + row_id[valid]
+        order = np.argsort(key, kind="stable")
+        self.keys = key[order]
+        self.key_pids = row_pid[valid][order]
+        first = np.ones(len(order), bool)
+        first[1:] = self.keys[1:] != self.keys[:-1]
+        kept = np.sort(valid[order[first]])
+        self.q = row_q[kept]
+        self.ids = row_id[kept]
+        self.pids = row_pid[kept]
+        self.codes = all_codes[row_code[kept]]
+        self.counts = np.bincount(self.q, minlength=q_count)
+        self.start = np.cumsum(self.counts) - self.counts
+        self.rank = (np.arange(len(kept)) - self.start[self.q]).astype(
+            np.int32)
+
+    def cover(self, pos: np.ndarray) -> List[List[int]]:
+        """Greedy cover of each query's ADC top (``pos`` [Q, k], ranks
+        into the query's pool, -1 empty), in rank order: a candidate
+        none of whose copies lies in a chosen partition adds its first
+        copy's partition."""
+        qs, cols = np.nonzero(pos >= 0)
+        rows = self.start[qs] + pos[qs, cols]
+        keys = qs * self.span + self.ids[rows]
+        lo = np.searchsorted(self.keys, keys, "left")
+        n = np.searchsorted(self.keys, keys, "right") - lo
+        ends = np.cumsum(n)
+        # the copies of every candidate, candidate after candidate
+        copies = self.key_pids[np.repeat(lo - ends + n, n)
+                               + np.arange(n.sum())].tolist()
+        refine: List[List[int]] = [[] for _ in range(len(pos))]
+        chosen: List[set] = [set() for _ in range(len(pos))]
+        a = 0
+        for qi, pid, b in zip(qs.tolist(), self.pids[rows].tolist(),
+                              ends.tolist()):
+            if chosen[qi].isdisjoint(copies[a:b]):
+                refine[qi].append(pid)
+                chosen[qi].add(pid)
+            a = b
+        return refine
 
 
 class ScanStage:
@@ -101,39 +181,10 @@ class ScanStage:
         partitions that cover the ADC top."""
         from repro.baselines.pq import adc_lut_batch
         q_count = len(probes_all)
-        cand_pids: List[np.ndarray] = []
-        cand_codes: List[np.ndarray] = []
-        cand_ids: List[np.ndarray] = []
-        id_pids: List[Dict[int, List[int]]] = []  # id -> probed pids
-        with host_span("scan.adc_pool"):
-            for qi in range(q_count):
-                ids_l, pids_l, codes_l = [], [], []
-                for pid in probes_all[qi]:
-                    codes = objs.get(pid)
-                    if codes is None:
-                        continue
-                    cnt = codes.shape[0]
-                    ids_l.append(pag.plist[pid, :cnt].astype(np.int64))
-                    pids_l.append(np.full(cnt, pid, np.int32))
-                    codes_l.append(codes)
-                if ids_l:
-                    ids_c = np.concatenate(ids_l)
-                    pids_c = np.concatenate(pids_l)
-                    keep = dedup_first(ids_c)  # redundant copies score once
-                    cand_pids.append(pids_c[keep])
-                    cand_codes.append(np.concatenate(codes_l)[keep])
-                    cand_ids.append(ids_c[keep])
-                    by_id: Dict[int, List[int]] = {}
-                    for i, cid in zip(pids_c, ids_c):
-                        by_id.setdefault(int(cid), []).append(int(i))
-                    id_pids.append(by_id)
-                else:
-                    cand_pids.append(np.zeros(0, np.int32))
-                    cand_codes.append(np.zeros((0, codebook.M), np.uint8))
-                    cand_ids.append(np.zeros(0, np.int64))
-                    id_pids.append({})
-
-        c_max = max((len(p) for p in cand_pids), default=0)
+        with host_span("scan.adc_pool") as sp:
+            pool = _AdcPool(probes_all, objs, pag.plist, codebook.M)
+            sp.set(rows=pool.n_rows, kept=len(pool.ids))
+        c_max = int(pool.counts.max(initial=0))
         if c_max == 0:
             return [[] for _ in range(q_count)]
         m = codebook.M
@@ -141,38 +192,19 @@ class ScanStage:
         with host_span("scan.adc_lut"):
             codes_pad = np.zeros((rows, width, m), np.uint8)
             pos_pad = np.full((rows, width), -1, np.int32)
-            for qi in range(q_count):
-                n = len(cand_pids[qi])
-                if n:
-                    codes_pad[qi, :n] = cand_codes[qi]
-                    pos_pad[qi, :n] = np.arange(n, dtype=np.int32)
+            codes_pad[pool.q, pool.rank] = pool.codes
+            pos_pad[pool.q, pool.rank] = pool.rank
             luts = np.zeros((rows, m, 256), np.float32)
             luts[:q_count] = adc_lut_batch(codebook,
                                            np.asarray(queries, np.float32))
         with host_span("scan.adc_launch",
                        h2d_bytes=luts.nbytes + codes_pad.nbytes
                        + pos_pad.nbytes, slots=rows * width,
-                       filled=sum(map(len, cand_pids))):
+                       filled=len(pool.ids)):
             _, pos = ops.pq_adc_masked(
                 jnp.asarray(luts), jnp.asarray(codes_pad),
                 jnp.asarray(pos_pad), k=rerank_k, block_c=self.scan_block)
             pos = np.asarray(pos)[:q_count]
 
-        refine_all: List[List[int]] = []
         with host_span("scan.cover_select"):
-            for qi in range(q_count):
-                chosen: List[int] = []
-                chosen_set: set = set()
-                for p in pos[qi]:
-                    if p < 0:
-                        continue
-                    copies = id_pids[qi].get(int(cand_ids[qi][p]))
-                    if copies is None:  # defensive: scored row has copies
-                        copies = [int(cand_pids[qi][p])]
-                    if chosen_set.intersection(copies):
-                        continue  # a selected partition holds a copy
-                    pid = int(cand_pids[qi][p])
-                    chosen.append(pid)
-                    chosen_set.add(pid)
-                refine_all.append(chosen)
-        return refine_all
+            return pool.cover(pos)
