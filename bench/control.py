@@ -1,15 +1,16 @@
 #!/usr/bin/env python3
 """Readings that the limits of ``correct`` are set from.
 
-    python3 bench/control.py --seeds 11,12,13 --seconds 30
+    python3 bench/control.py --seeds 11,12,13 --seconds 30 \
+        [--cells deep96-float.c64-L64]
 
-For each seed, one process builds one deployment (float residuals and
-PQ codes written together, so both cells serve from it) and
-runs each cell's warm-up and window twice: once as the program is
-(``program``: the lower readings), once with the bfloat16 reference in
-place of the scan kernels (``control``: the upper readings). Each prints
-one JSON line with the numbers compared. The benchmark's own runs never
-run this. Needs a TPU, like ``run.py``.
+For each seed, one process builds one deployment (where a cell scans PQ
+codes, float residuals and PQ codes written together, so every cell
+serves from it) and runs each cell's warm-up and window twice: once as
+the program is (``program``: the lower readings), once with the bfloat16
+reference in place of the scan kernels (``control``: the upper
+readings). Each prints one JSON line with the numbers compared. The
+benchmark's own runs never run this. Needs a TPU, like ``run.py``.
 """
 from __future__ import annotations
 
@@ -50,15 +51,18 @@ def readings(cells, seed: int, seconds, log=print) -> list:
     import data
     import harness
     configs = [c.config for c in cells]
-    keys = ("n", "d", "n_queries", "query_noise", "vectors_seed",
+    keys = ("n", "d", "dtype", "n_queries", "query_noise", "vectors_seed",
             "index_seed", "build", "storage")
     if any(c[key] != configs[0][key] for c in configs for key in keys):
         raise ValueError("the cells do not share one deployment")
     seeds = data.sub_seeds(seed)
     pq_m = {c["plane"].get("pq_m") for c in configs} - {None}
-    cfg = dict(configs[0], plane={"compression": "pq",
-                                  "pq_m": pq_m.pop() if pq_m else 8})
-    dep = harness.deploy(cfg, seeds, compression="pq")
+    if pq_m:
+        cfg = dict(configs[0], plane={"compression": "pq",
+                                      "pq_m": pq_m.pop()})
+        dep = harness.deploy(cfg, seeds, compression="pq")
+    else:
+        dep = harness.deploy(configs[0], seeds)
     out = []
     for cell in cells:
         for variant in VARIANTS:
@@ -79,6 +83,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--cells", default=",".join(CELLS),
+                    help="cells that share one deployment, comma-separated")
     args = ap.parse_args(argv)
     import harness
     import jax
@@ -86,7 +92,7 @@ def main(argv=None) -> int:
         print("control: needs a TPU", file=sys.stderr)
         return 2
     harness.enable_compile_cache()
-    cells = [harness.load_cell(c) for c in CELLS]
+    cells = [harness.load_cell(c) for c in args.cells.split(",")]
     for seed in (int(s) for s in args.seeds.split(",")):
         readings(cells, seed, args.seconds)
     return 0

@@ -16,21 +16,28 @@ from typing import Dict, Tuple
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 
 
-def l2_topk_masked(q: int, c: int, d: int, k: int) -> Tuple[int, int]:
-    """(flops, bytes) of ``l2_topk_masked`` at pools [q, c, d] f32:
-    a subtract, a multiply and an add per pooled coordinate; the pools,
-    their int32 ids and the queries read, (d2, id) pairs written."""
+def l2_topk_masked(q: int, c: int, d: int, k: int, pool_itemsize: int = 4,
+                   query_itemsize: int = 4) -> Tuple[int, int]:
+    """(flops, bytes) of ``l2_topk_masked`` at pools [q, c, d]:
+    a subtract, a multiply and an add per pooled coordinate; the pools
+    at ``pool_itemsize`` bytes an element (4 for float32, 1 for uint8),
+    their int32 ids and the queries at ``query_itemsize`` read, (d2, id)
+    pairs written."""
     flops = 3 * q * c * d
-    nbytes = 4 * q * c * d + 4 * q * c + 4 * q * d + 8 * q * k
+    nbytes = pool_itemsize * q * c * d + 4 * q * c + query_itemsize * q * d \
+        + 8 * q * k
     return flops, nbytes
 
 
-def pq_adc_masked(q: int, c: int, m: int, k: int) -> Tuple[int, int]:
-    """(flops, bytes) of ``pq_adc_masked`` at codes [q, c, m] uint8:
-    one add per looked-up entry; the uint8 codes, their int32 ids and
-    the per-query [m, 256] f32 tables read, (d2, id) pairs written."""
+def pq_adc_masked(q: int, c: int, m: int, k: int, code_itemsize: int = 1,
+                  lut_itemsize: int = 4) -> Tuple[int, int]:
+    """(flops, bytes) of ``pq_adc_masked`` at codes [q, c, m]:
+    one add per looked-up entry; the codes at ``code_itemsize`` bytes
+    (1 for uint8), their int32 ids and the per-query [m, 256] tables at
+    ``lut_itemsize`` read, (d2, id) pairs written."""
     flops = q * c * m
-    nbytes = q * c * m + 4 * q * c + 4 * q * m * 256 + 8 * q * k
+    nbytes = code_itemsize * q * c * m + 4 * q * c \
+        + lut_itemsize * q * m * 256 + 8 * q * k
     return flops, nbytes
 
 

@@ -17,6 +17,17 @@ splits it (of any size: seeds may exceed 32 bits) into independent
 32-bit seeds for the order of the micro-batches, the store's latency
 draws and the sample of kernel launches that is checked. So every seed
 gets the same vectors, queries and batches, in another order.
+
+The vectors come in the configuration's ``dtype``. ``float32`` is the
+mixture as drawn. ``uint8`` and ``int8`` map it onto the type's whole
+range ``[lo, hi]`` ([0, 255] or [-128, 127]) by one affine map fixed by
+the base alone: with ``b0``, ``b1`` the least and the greatest
+coordinate of the float base,
+
+    v -> clip(rint(lo + (v - b0) * (hi - lo) / (b1 - b0)), lo, hi)
+
+taken in float64. The queries, perturbed in float as for ``float32``,
+go through the same map, so a query beyond the base's range is clipped.
 """
 from __future__ import annotations
 
@@ -25,6 +36,8 @@ from typing import Dict, Tuple
 import numpy as np
 
 PARTS = ("batches", "store", "sample")
+INT_RANGES = {"uint8": (0, 255), "int8": (-128, 127)}
+DTYPES = ("float32",) + tuple(INT_RANGES)
 
 
 def sub_seeds(seed: int) -> Dict[str, int]:
@@ -55,9 +68,23 @@ def clustered(n: int, d: int, n_queries: int, seed: int,
 
 
 def vectors(config: dict) -> Tuple[np.ndarray, np.ndarray]:
-    """The configuration's base and queries."""
-    return clustered(config["n"], config["d"], config["n_queries"],
-                     config["vectors_seed"], config["query_noise"])
+    """The configuration's base and queries, in its ``dtype``."""
+    dtype = config["dtype"]
+    if dtype not in DTYPES:
+        raise ValueError(f"dtype {dtype!r}: the benchmark generates "
+                         f"{list(DTYPES)} only")
+    base, queries = clustered(config["n"], config["d"], config["n_queries"],
+                              config["vectors_seed"], config["query_noise"])
+    if dtype == "float32":
+        return base, queries
+    lo, hi = INT_RANGES[dtype]
+    b0, b1 = float(base.min()), float(base.max())
+    scale = (hi - lo) / (b1 - b0)
+
+    def to_int(v):
+        x = lo + (v.astype(np.float64) - b0) * scale
+        return np.clip(np.rint(x), lo, hi).astype(dtype)
+    return to_int(base), to_int(queries)
 
 
 def batch_order(n_batches: int, seed: int) -> np.ndarray:

@@ -9,7 +9,8 @@ of the closed loop and the search effort). A run:
    the batches in the order the seed draws;
 2. builds the index with the program's ``build_pag``, from the
    configuration's ``index_seed``, and writes the partitions with
-   ``write_partitions`` into the simulated object store;
+   ``write_partitions`` into the simulated object store, as many
+   replicas as it states;
 3. warms up: one pass over the whole query set in the window's own
    micro-batches, which compiles every shape the window will use;
 4. runs the window: a closed loop of ``clients`` queries submitted one by
@@ -21,7 +22,9 @@ of the closed loop and the search effort). A run:
    each through its own reader ``metrics/<metric>.py``.
 
 The program is imported from ``<checkout>/src``; the benchmark hands it
-only the generated inputs and the configuration's settings.
+only the generated inputs, in the configuration's ``dtype``, and the
+configuration's settings. A configuration or traffic mix that states
+what the harness does not honour is refused before any work.
 """
 from __future__ import annotations
 
@@ -45,6 +48,10 @@ ROOT = BENCH.parent
 CACHE_DIR = ROOT / ".jax_cache"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 SAMPLED_LAUNCHES = 2    # launches of each kernel kept for the parity check
+METRICS = ("squared_l2",)   # the only metric reference.py computes
+STORAGE_KEYS = {"preset", "n_shards", "replicas"}
+TRAFFIC_KEYS = {"name", "arrivals", "clients", "search", "why",
+                "recall_at_10_min"}
 
 for _p in (str(BENCH), str(ROOT / "src")):
     if _p not in sys.path:
@@ -113,6 +120,24 @@ def count_compiles():
 
 
 # ------------------------------------------------------------ deployment
+def refuse_unhonoured(config: dict) -> None:
+    """ValueError where the configuration states what the harness would
+    not honour: a metric other than squared L2, a dtype the generator
+    does not make, or a storage setting it does not pass on."""
+    name = config.get("name")
+    if config.get("metric") not in METRICS:
+        raise ValueError(f"config {name!r}: metric {config.get('metric')!r}"
+                         f" has no reference; the benchmark checks "
+                         f"{list(METRICS)} only")
+    if config.get("dtype") not in data.DTYPES:
+        raise ValueError(f"config {name!r}: dtype {config.get('dtype')!r};"
+                         f" the benchmark generates {list(data.DTYPES)} only")
+    unknown = set(config["storage"]) - STORAGE_KEYS
+    if unknown:
+        raise ValueError(f"config {name!r}: storage knows no "
+                         f"{sorted(unknown)}; known: {sorted(STORAGE_KEYS)}")
+
+
 @dataclasses.dataclass
 class Deployment:
     base: np.ndarray
@@ -128,7 +153,9 @@ def deploy(config: dict, seeds: Dict[str, int],
     the same index for every run seed, which draws only the store's
     latencies. ``compression`` overrides the plane's payloads ("pq"
     writes the float residuals and the PQ codes, so one deployment
-    serves both)."""
+    serves both). The vectors reach the program in the configuration's
+    dtype."""
+    refuse_unhonoured(config)
     from repro.core.distributed import ShardedServing
     from repro.core.pag import build_pag
     from repro.core.search import write_partitions
@@ -144,12 +171,13 @@ def deploy(config: dict, seeds: Dict[str, int],
                                              seed=seeds["store"]))
     with count_compiles() as write_c:
         write_partitions(pag, base, store, n_shards=st["n_shards"],
+                         replicas=st["replicas"],
                          compression=compression or plane["compression"],
                          pq_m=plane.get("pq_m", 8),
                          pq_seed=config["index_seed"])
     t3 = time.perf_counter()
     srv = ShardedServing(pag=pag, store=store, n_shards=st["n_shards"],
-                         dim=config["d"])
+                         replicas=st["replicas"], dim=config["d"])
     return Deployment(base, queries, srv, store,
                       {"data_s": t1 - t0, "build_s": t2 - t1,
                        "build_compiles": build_c[0],
@@ -165,6 +193,7 @@ def search_config(config: dict, traffic: dict):
     s, plane = traffic["search"], config["plane"]
     return SearchConfig(L=s["L"], k=config["k"],
                         n_probe_max=s["n_probe_max"],
+                        replicas=config["storage"]["replicas"],
                         compression=plane["compression"],
                         pq_m=plane.get("pq_m", 8),
                         rerank_k=plane.get("rerank_k", 32))
@@ -238,7 +267,9 @@ def run_batches(fe, queries: np.ndarray, batches: List[np.ndarray],
 
 class Capture:
     """Wraps the program's scan kernels (``repro.kernels.ops``) while
-    installed: records every launch's shape, and keeps a sample of
+    installed: records every launch's shape as ``costs.KERNELS`` take it
+    (the pool's or codes' shape, k, the itemsize of the pool or codes and
+    of the query side: queries or tables), and keeps a sample of
     ``keep`` launches per kernel (inputs and outputs), drawn from the
     seed by reservoir sampling over the launches as they come."""
 
@@ -250,7 +281,8 @@ class Capture:
 
     def _record(self, name, args, kw, out):
         k = kw.get("k", 10)
-        self.shapes[name].append(tuple(args[1].shape) + (k,))
+        self.shapes[name].append(tuple(args[1].shape) + (
+            k, args[1].dtype.itemsize, args[0].dtype.itemsize))
         seen = len(self.shapes[name])
         if seen <= self.keep:
             self.sample[name].append((args, kw, out))
@@ -330,7 +362,8 @@ def check(base: np.ndarray, queries: np.ndarray, win: Window,
     out = {
         "answers_failed": (int((~full).sum()), 0, "max"),
         "d2_gap": (d2_gap, limits["d2_gap"], "max"),
-        "recall_at_10": (reference.recall(win.ids, gt[win.q_idx], k),
+        "recall_at_10": (reference.recall(base, queries, win.q_idx, win.ids,
+                                          gt[win.q_idx], k),
                          recall_min, "min"),
     }
     pools = {"l2_topk_masked": reference.pool_d2_l2,
@@ -368,6 +401,14 @@ def read_metric(name: str, ctx: dict) -> Optional[float]:
     return mod.read(ctx)
 
 
+def recall_floor(config: dict, traffic: dict) -> float:
+    """The least ``recall_at_10`` a run may read: the traffic mix's own
+    floor where it states one (a lower search effort than the
+    configuration's floor was set at), else the configuration's."""
+    return traffic.get("recall_at_10_min",
+                       config["guarantees"]["recall_at_10_min"])
+
+
 # ------------------------------------------------------------------- run
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_start: float, peaks: Optional[dict] = None, log=print) -> dict:
@@ -390,7 +431,7 @@ def run_deployed(cell: Cell, dep: Deployment, seeds: Dict[str, int],
     version of the program in place there."""
     import jax
     config, traffic = cell.config, cell.traffic
-    unknown = set(traffic) - {"name", "arrivals", "clients", "search", "why"}
+    unknown = set(traffic) - TRAFFIC_KEYS
     if traffic["arrivals"] != "closed" or unknown:
         raise ValueError(f"traffic {traffic['name']!r}: this generator runs "
                          f"closed loops only, and knows no {sorted(unknown)}")
@@ -453,7 +494,7 @@ def run_deployed(cell: Cell, dep: Deployment, seeds: Dict[str, int],
     t_check = time.perf_counter()
     limits = read_json("limits.json")["limits"]
     checks = check(base, queries, win, capture, config["k"], limits,
-                   config["guarantees"]["recall_at_10_min"])
+                   recall_floor(config, traffic))
     log(json.dumps({"phase": "check", "s": time.perf_counter() - t_check}))
     # what a reader in metrics/ may read
     ctx = {"setup_s": setup_s, "window": win, "trace": tr,

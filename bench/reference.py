@@ -9,6 +9,7 @@ kernel launches' own inputs as the program handed them to the kernel.
   device at ``highest`` matmul precision, in chunks of queries.
 - ``answer_d2``: the squared distance of each returned (query, id), in
   float64 on the host.
+- ``recall``: the share of the exact k nearest among the returned k.
 - ``pool_d2_l2`` / ``pool_d2_pq``: every pooled candidate's distance of
   a captured ``l2_topk_masked`` / ``pq_adc_masked`` launch, in float32
   (``highest``) or, for the control, bfloat16.
@@ -17,6 +18,13 @@ kernel launches' own inputs as the program handed them to the kernel.
 - ``control_l2_topk_masked`` / ``control_pq_adc_masked``: the reference
   computed in bfloat16 (the precision below the float32 that DEEP-1B
   states), with the kernels' signatures, to put in their place.
+
+Integer vectors (``uint8``, ``int8``) go through float32 unchanged:
+every coordinate, product, partial sum and squared distance is an
+integer below 2**24, so float32 holds each exactly and ``exact_knn``
+and ``pool_d2_l2`` give the exact integer distances.
+``check_exact`` refuses a width at which that would no longer hold
+(d = 129 is the widest for ``uint8``, so BIGANN's 128 fits).
 """
 from __future__ import annotations
 
@@ -28,6 +36,27 @@ import numpy as np
 
 INF = np.float32(3.4e38)
 HIGHEST = jax.lax.Precision.HIGHEST
+F32_EXACT = 2 ** 24     # every integer up to this is exact in float32
+
+
+def int_bound(dtype, d: int) -> int:
+    """The largest magnitude that any term, partial sum or result of the
+    squared-L2 references takes on integer vectors of ``dtype`` and
+    width ``d``: ``|q|^2 - 2 q.x`` reaches 2 d m^2 (unsigned) or
+    3 d m^2 (signed), m the type's largest magnitude; a squared
+    distance reaches d (max - min)^2."""
+    info = np.iinfo(dtype)
+    m = max(-int(info.min), int(info.max))
+    lead = 3 if info.min < 0 else 2
+    return max(lead * d * m * m, d * (int(info.max) - int(info.min)) ** 2)
+
+
+def check_exact(dtype, d: int) -> None:
+    """Raise where float32 would not hold integer distances exactly."""
+    if np.issubdtype(dtype, np.integer) and int_bound(dtype, d) >= F32_EXACT:
+        raise ValueError(f"{np.dtype(dtype).name} vectors of width {d}: "
+                         f"squared distances reach {int_bound(dtype, d)}, "
+                         f"beyond float32's exact integers (2**24)")
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -40,7 +69,9 @@ def _knn_chunk(q, base, base_sq, *, k: int):
 
 def exact_knn(base: np.ndarray, queries: np.ndarray, k: int,
               chunk: int = 128) -> np.ndarray:
-    """Exact k nearest base ids [Q, k] of every query, nearest first."""
+    """Exact k nearest base ids [Q, k] of every query, nearest first
+    (among equal distances, in no particular order)."""
+    check_exact(base.dtype, base.shape[1])
     base_d = jnp.asarray(base, jnp.float32)
     base_sq = jnp.sum(base_d * base_d, axis=1)
     out = []
@@ -64,20 +95,50 @@ def answer_d2(base: np.ndarray, queries: np.ndarray, q_idx: np.ndarray,
     return np.einsum("qkd,qkd->qk", diff, diff)
 
 
-def recall(result_ids: np.ndarray, gt_ids: np.ndarray, k: int) -> float:
-    """Share of the exact k nearest found among the returned k."""
-    hits = sum(len(set(r[:k].tolist()) & set(g[:k].tolist()))
-               for r, g in zip(result_ids, gt_ids))
-    return hits / (len(gt_ids) * k)
+def recall(base: np.ndarray, queries: np.ndarray, q_idx: np.ndarray,
+           result_ids: np.ndarray, gt_ids: np.ndarray, k: int) -> float:
+    """Share of the exact k nearest found among the returned k, over the
+    answers to ``queries[q_idx]``; ``gt_ids`` [N, k] are their exact k
+    nearest (``exact_knn``).
+
+    A float base counts the returned ids that are among ``gt_ids``. On an
+    integer base distances are exact and tie often, so the k-th nearest
+    may be any of several ids: there a returned id of the base counts
+    where its exact distance is no larger than the exact k-th nearest
+    distance, each id once and at most k a query (ann-benchmarks' k-NN
+    recall, with no epsilon)."""
+    if not np.issubdtype(base.dtype, np.integer):
+        hits = sum(len(set(r[:k].tolist()) & set(g[:k].tolist()))
+                   for r, g in zip(result_ids, gt_ids))
+        return hits / (len(gt_ids) * k)
+    ids = result_ids[:, :k]
+    kth = answer_d2(base, queries, q_idx, gt_ids[:, k - 1:k])
+    near = (ids >= 0) & (ids < len(base)) \
+        & (answer_d2(base, queries, q_idx, ids) <= kth)
+    kept = np.sort(np.where(near, ids, -1), axis=1)
+    distinct = (kept >= 0) & np.concatenate(
+        [np.ones((len(kept), 1), bool), kept[:, 1:] != kept[:, :-1]], axis=1)
+    hits = np.minimum(distinct.sum(axis=1), k).sum()
+    return float(hits) / (len(gt_ids) * k)
 
 
 # ------------------------------------------------------ kernel references
 @functools.partial(jax.jit, static_argnames=("dtype",))
 def pool_d2_l2(q, pools, *, dtype=jnp.float32):
     """q [Q, d], pools [Q, C, d] -> squared distances [Q, C] float32,
-    the differences taken in ``dtype``."""
+    the differences and their squares held in ``dtype``, summed in
+    float32. Exact on integer vectors in float32 (module docstring)."""
     diff = pools.astype(dtype) - q.astype(dtype)[:, None, :]
-    return jnp.sum(diff * diff, axis=-1, dtype=jnp.float32)
+    sq = diff * diff
+    info = jnp.finfo(dtype)
+    if info.bits < 32:
+        # XLA may keep the product in float32 (excess precision); round
+        # it as a kernel holding it in ``dtype`` would. On integer
+        # vectors the differences are exact in bfloat16, their squares
+        # are not.
+        sq = jax.lax.reduce_precision(sq, exponent_bits=info.nexp,
+                                      mantissa_bits=info.nmant)
+    return jnp.sum(sq, axis=-1, dtype=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))

@@ -13,7 +13,9 @@ line of standard output is the result: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
 ``--trace 1`` its per-layer metrics and a ``breakdown``), ``device`` and
 ``checks``. Without a TPU, or with fewer chips than the cell asks for, it
-exits non-zero and prints no result.
+exits non-zero and prints no result; so it does, naming what it refuses,
+for a configuration that states a metric, dtype or storage setting that
+the harness does not honour.
 """
 from __future__ import annotations
 
@@ -39,6 +41,7 @@ def main(argv=None) -> int:
 
     import harness
     cell = harness.load_cell(args.workload)
+    harness.refuse_unhonoured(cell.config)    # before the chip is touched
     import jax
     devices = jax.devices()
     if devices[0].platform != "tpu" or len(devices) < cell.chips:

@@ -6,6 +6,7 @@ broken underneath."""
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import json
 import sys
 import time
@@ -26,6 +27,7 @@ import harness  # noqa: E402
 import reference  # noqa: E402
 
 FLOAT, PQ = "deep96-float.c64-L256", "deep96-pq.c64-L256"
+LOW = "deep96-float.c64-L64"    # the float plane at low search effort
 TINY = {"n": 1200, "n_queries": 128}    # two micro-batches of 64
 
 
@@ -52,7 +54,7 @@ def test_generator_matches_the_program_mixture():
 
 def test_every_seed_gets_the_same_vectors_in_another_order():
     config = {"n": 300, "d": 96, "n_queries": 8, "query_noise": 0.1,
-              "vectors_seed": 3}
+              "vectors_seed": 3, "dtype": "float32"}
     base_a, q_a = data.vectors(config)
     base_b, q_b = data.vectors(config)
     np.testing.assert_array_equal(base_a, base_b)
@@ -251,7 +253,7 @@ def test_run_refuses_without_a_tpu(capsys):
 @pytest.fixture(scope="module")
 def tiny():
     cells = {}
-    for name in (FLOAT, PQ):
+    for name in (FLOAT, PQ, LOW):
         cells[name] = harness.load_cell(name)
         cells[name].config.update(TINY)
     seeds = data.sub_seeds(2 ** 33 + 5)
@@ -266,7 +268,7 @@ def _run(tiny, name, swap=None):
                                 log=lambda s: None)
 
 
-@pytest.mark.parametrize("name", [FLOAT, PQ])
+@pytest.mark.parametrize("name", [FLOAT, PQ, LOW])
 def test_sound_run_is_correct(tiny, name):
     res = _run(tiny, name)
     assert res["correct"], res["checks"]
@@ -274,13 +276,35 @@ def test_sound_run_is_correct(tiny, name):
     assert list(res)[-1] == "checks"
     assert set(res["metrics"]) == {"qps", "latency_p95_ms", "recall_at_10",
                                    "bytes_per_query", "setup_s"}
-    kernels = {FLOAT: {"l2_topk_masked_gap"},
+    kernels = {FLOAT: {"l2_topk_masked_gap"}, LOW: {"l2_topk_masked_gap"},
                PQ: {"l2_topk_masked_gap", "pq_adc_masked_gap"}}[name]
     assert kernels <= set(res["checks"])
+    cell = tiny[0][name]
+    floor = res["checks"]["recall_at_10"]["limit"]
+    if name == LOW:     # the traffic mix's own floor, not the config's
+        assert floor == cell.traffic["recall_at_10_min"] \
+            < cell.config["guarantees"]["recall_at_10_min"]
+    else:
+        assert "recall_at_10_min" not in cell.traffic
+        assert floor == cell.config["guarantees"]["recall_at_10_min"]
+
+
+def test_a_traffic_floor_replaces_the_config_floor_for_its_cells_only():
+    config = {"guarantees": {"recall_at_10_min": 0.7}}
+    assert harness.recall_floor(config, {"recall_at_10_min": 0.3}) == 0.3
+    assert harness.recall_floor(config, {"clients": 64}) == 0.7
+    assert config == {"guarantees": {"recall_at_10_min": 0.7}}
+    with pytest.raises(ValueError, match="burst"):
+        harness.run_deployed(
+            dataclasses.replace(harness.load_cell(LOW),
+                                traffic={"name": "x", "arrivals": "closed",
+                                         "burst": 4}),
+            None, data.sub_seeds(1), 0.0, False, 0.0)
 
 
 @pytest.mark.parametrize("name,fails", [(FLOAT, "d2_gap"),
-                                        (PQ, "pq_adc_masked_gap")])
+                                        (PQ, "pq_adc_masked_gap"),
+                                        (LOW, "d2_gap")])
 def test_bf16_control_is_not_correct(tiny, name, fails):
     res = _run(tiny, name, swap=control.bf16_kernels)
     assert not res["correct"]
@@ -354,8 +378,10 @@ def altered_pq():
 
 @pytest.mark.parametrize("name,fault", [
     (FLOAT, stale_answers), (FLOAT, half_batch), (FLOAT, altered_l2),
-    (PQ, altered_pq)], ids=["stale", "half_batch", "altered_l2",
-                            "altered_pq"])
+    (PQ, altered_pq), (LOW, stale_answers), (LOW, half_batch),
+    (LOW, altered_l2)], ids=["stale", "half_batch", "altered_l2",
+                             "altered_pq", "stale-L64", "half_batch-L64",
+                             "altered_l2-L64"])
 def test_broken_path_is_not_correct(tiny, name, fault):
     res = _run(tiny, name, swap=fault)
     assert not res["correct"], res["checks"]

@@ -95,7 +95,7 @@ def test_launch_stats_match_the_launch_shapes(traced, name, span, kernel):
     (stats,) = [s[3] for s in program if s[2] == stages.PREFIX + span]
     (shape,) = capture.shapes[kernel]
     (args, _, _), = capture.sample[kernel]
-    rows, width, inner, _ = shape
+    rows, width, inner, *_ = shape
     assert stats["slots"] == rows * width
     assert stats["filled"] == int((np.asarray(args[2]) >= 0).sum())
     if kernel == "l2_topk_masked":        # queries, pool vectors, ids
