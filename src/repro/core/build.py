@@ -21,6 +21,17 @@ from repro.core.distances import cdist2, topk_l2
 from repro.core.graph_search import greedy_search, robust_prune
 
 
+NATIVE_DTYPES = ("uint8", "int8")   # integer bases kept in their own type
+
+
+def vector_dtype(dtype) -> str:
+    """Element type the index keeps a base of ``dtype`` in, on the device
+    and in storage: 1-byte integer bases (BIGANN's uint8) as they are,
+    every other base as float32."""
+    name = np.dtype(dtype).name
+    return name if name in NATIVE_DTYPES else "float32"
+
+
 @dataclasses.dataclass
 class PG:
     """Mutable proximity-graph arena.
@@ -29,12 +40,17 @@ class PG:
     insert/reverse passes); columns [R_prune, R_total) are NSW-style random
     long-range edges fixed at init — they guarantee navigability across
     strongly clustered data (greedy beam search otherwise stalls at
-    cluster boundaries; see tests/test_pag.py)."""
+    cluster boundaries; see tests/test_pag.py).
+
+    ``A`` is float32 on the host, where the build computes (exact on
+    1-byte integers); ``dtype`` is the base's element type
+    (``vector_dtype``), in which ``device_arrays`` uploads it."""
     A: np.ndarray          # [m_cap, d] float32 (rows >= n_nodes are zeros)
     nbrs: np.ndarray       # [m_cap, R_total] int32, sentinel = m_cap
     n_nodes: int
     entry: int
     R_prune: int = 0       # 0 -> whole width prunable
+    dtype: str = "float32"  # element type of the aggregation points
 
     def __post_init__(self):
         if self.R_prune == 0:
@@ -48,8 +64,13 @@ class PG:
     def R(self) -> int:
         return self.R_prune
 
+    def native_A(self) -> np.ndarray:
+        """The aggregation points in the base's element type."""
+        return self.A if self.A.dtype == self.dtype else self.A.astype(
+            self.dtype)
+
     def device_arrays(self):
-        return (jnp.asarray(self.A), jnp.asarray(self.nbrs),
+        return (jnp.asarray(self.native_A()), jnp.asarray(self.nbrs),
                 jnp.int32(self.n_nodes), jnp.int32(self.entry))
 
 

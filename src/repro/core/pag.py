@@ -16,7 +16,7 @@ from typing import Dict, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.build import PG, build_pg, insert_nodes
+from repro.core.build import PG, build_pg, insert_nodes, vector_dtype
 from repro.core.graph_search import greedy_search
 
 INF = np.float32(3.4e38)
@@ -40,8 +40,10 @@ class PAG:
         return self.pg.n_nodes
 
     def arrays(self):
+        """The in-memory half as named arrays (``core.index`` saves them);
+        the aggregation points in the base's element type."""
         return {
-            "A": self.pg.A, "nbrs": self.pg.nbrs,
+            "A": self.pg.native_A(), "nbrs": self.pg.nbrs,
             "node_src": self.node_src, "radius": self.radius,
             "plist": self.plist, "pcount": self.pcount,
             "meta": np.array([self.pg.n_nodes, self.pg.entry,
@@ -53,8 +55,12 @@ class PAG:
     def from_arrays(cls, arrs) -> "PAG":
         n_nodes, entry, r_prune, cap, n_total = [int(v) for v in
                                                  arrs["meta"]]
-        pg = PG(A=np.asarray(arrs["A"]), nbrs=np.asarray(arrs["nbrs"]),
-                n_nodes=n_nodes, entry=entry, R_prune=r_prune)
+        A = np.asarray(arrs["A"])
+        dtype = vector_dtype(A.dtype)
+        if dtype != "float32":  # the host copy the build computes on
+            A = A.astype(np.float32)
+        pg = PG(A=A, nbrs=np.asarray(arrs["nbrs"]), n_nodes=n_nodes,
+                entry=entry, R_prune=r_prune, dtype=dtype)
         return cls(pg=pg, node_src=np.asarray(arrs["node_src"]),
                    radius=np.asarray(arrs["radius"]),
                    plist=np.asarray(arrs["plist"]),
@@ -154,6 +160,9 @@ def build_pag(x: np.ndarray, *, p: float = 0.2, k: int = 8,
 
     Returns the in-memory PAG; residual vectors are addressed by original
     dataset ids (the storage layer materializes per-partition objects).
+    The build computes in float32; the graph records the base's element
+    type (``vector_dtype``: uint8 / int8 stay native), in which it goes
+    to the device and its partitions to storage.
     """
     t0 = time.time()
     n, d = x.shape
@@ -266,6 +275,7 @@ def build_pag(x: np.ndarray, *, p: float = 0.2, k: int = 8,
         "p": p, "gamma1": gamma1, "gamma2": gamma2, "lam": lam,
         "redundancy": redundancy, "drs": use_drs,
     }
+    pg.dtype = vector_dtype(x.dtype)
     return PAG(pg=pg, node_src=node_src, radius=radius, plist=plist,
                pcount=pcount, cap=cap, n_total=n, build_stats=stats)
 

@@ -86,10 +86,13 @@ partition ``pid`` with ``S`` shards / ``R`` replicas:
 Code objects are colocated with their float siblings (one shard loss
 kills both), carry put-time checksums, and replicate round-robin like
 the float path. Ids are NOT stored in code objects — the in-memory
-``pag.plist`` already maps partition rows to original ids. The float
-object's id column bit-casts ``int32`` ids into the ``float32`` column
-(``_pack_ids``/``_unpack_ids``) so billion-scale ids survive exactly
-(a plain float cast is only exact below 2^24).
+``pag.plist`` already maps partition rows to original ids. A residual
+object (``KeySpace.pack``) keeps the base's element type: each row is a
+4-byte int32 id, bit-cast (``_pack_ids``/``_unpack_ids``, exact for
+billion-scale ids), then the vector. A float32 row is ``[1 + d]``
+float32 (the id in column 0); a uint8 / int8 row (BIGANN-style bases)
+is ``[4 + d]`` bytes, and such a base stays in its type from the
+object to the scan kernel, and in the graph on the device.
 """
 from __future__ import annotations
 
@@ -108,7 +111,9 @@ from repro.dataplane.plan import (
     FetchPlan,
     KeySpace,
     app_probe_order as _app_probe_order_impl,
+    pack_ids,
     probe_orders,
+    unpack_ids,
 )
 from repro.dataplane.prefetch import PrefetchHandle
 from repro.dataplane.scan import ID_SENTINEL, INF, ScanStage, dedup_first
@@ -128,25 +133,13 @@ from repro.storage.simulator import (
 # pin the historical import site (repro.core.search)
 _dedup_first = dedup_first
 _app_probe_order = _app_probe_order_impl
+_pack_ids = pack_ids
+_unpack_ids = unpack_ids
 
 __all__ = [
     "ID_SENTINEL", "INF", "DegradedInfo", "SearchConfig", "SearchStats",
     "search_pag", "write_partitions",
 ]
-
-
-def _pack_ids(ids: np.ndarray) -> np.ndarray:
-    """Bit-cast int32 ids into the float32 id column of a partition
-    object. A plain value cast is only exact below 2^24 (float32 has a
-    24-bit mantissa); the bit-cast is exact for the whole int32 range,
-    so billion-scale ids survive storage round-trips."""
-    return np.ascontiguousarray(ids, np.int32).view(np.float32)
-
-
-def _unpack_ids(col: np.ndarray) -> np.ndarray:
-    """Inverse of ``_pack_ids``: float32 id column -> int64 ids."""
-    return np.ascontiguousarray(col, np.float32).view(np.int32) \
-        .astype(np.int64)
 
 
 def write_partitions(pag: PAG, x: np.ndarray, store: ObjectStore,
@@ -155,9 +148,11 @@ def write_partitions(pag: PAG, x: np.ndarray, store: ObjectStore,
                      pq_m: int = 8, pq_seed: int = 0):
     """Materialize per-partition residual objects in the storage layer.
 
-    Object = float32 [cnt, 1 + d]: column 0 carries the original id (a
-    BIT-CAST int32, exact for all ids — see ``_pack_ids``), columns 1:
-    the vector. Partitions are round-robined over ``n_shards`` logical
+    Object = rows of the original id (a BIT-CAST int32, exact for all
+    ids — see ``_pack_ids``) and the vector, in the base's element type
+    (``KeySpace.pack``): float32 [cnt, 1 + d] for float bases, uint8 /
+    int8 [cnt, 4 + d] for 1-byte integer bases. Partitions are
+    round-robined over ``n_shards`` logical
     shards (prefix/<shard>/<pid>) so failure injection can kill a shard
     (fault-tolerance tests). ``replicas=R`` writes R copies per
     partition: the primary under the legacy key and replica j under
@@ -172,6 +167,7 @@ def write_partitions(pag: PAG, x: np.ndarray, store: ObjectStore,
     like the float path. Returns the trained ``PQCodebook`` (or None)."""
     if compression not in ("none", "pq"):
         raise ValueError(f"unknown compression: {compression!r}")
+    layout = KeySpace(dtype=pag.pg.dtype)
     cb = None
     if compression == "pq":
         from repro.baselines.pq import encode_pq, train_pq
@@ -182,13 +178,11 @@ def write_partitions(pag: PAG, x: np.ndarray, store: ObjectStore,
     for pid in range(pag.n_parts):
         cnt = int(pag.pcount[pid])
         ids = pag.plist[pid, :cnt]
-        obj = np.zeros((cnt, x.shape[1] + 1), np.float32)
-        obj[:, 0] = _pack_ids(ids)
-        obj[:, 1:] = x[ids]
+        obj = layout.pack(ids, x[ids])
         for key in replica_keys(prefix, pid, n_shards, replicas):
             store.put(key, obj)
-        if cb is not None:
-            vecs.append(obj[:, 1:])
+        if cb is not None:  # PQ trains and encodes on a float32 view
+            vecs.append(np.asarray(x[ids], np.float32))
     if cb is not None:
         # one bulk encode: rows are encoded independently, so each slice
         # equals encoding its partition on its own
@@ -350,9 +344,14 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
     pg = pag.pg
     q_count = queries.shape[0]
     rows = pad_rows if pad_rows > q_count > 0 else q_count
+    # queries in the base's element type, as the graph and the pools
+    if pg.dtype != "float32" and queries.dtype != pg.dtype:
+        raise ValueError(f"{queries.dtype} queries for an index over "
+                         f"{pg.dtype} vectors")
+    queries = np.asarray(queries, pg.dtype)
     with host_span("search", queries=q_count, rows=rows):
         with host_span("graph") as sp:
-            q_dev = np.asarray(queries, np.float32)
+            q_dev = queries
             if rows > q_count:  # pad with copies of a real query
                 q_dev = np.concatenate(
                     [q_dev, np.repeat(q_dev[:1], rows - q_count, axis=0)])
@@ -380,7 +379,7 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
         if cfg.compression not in ("none", "pq"):
             raise ValueError(f"unknown compression: {cfg.compression!r}")
         pq = cfg.compression == "pq"
-        keyspace = KeySpace(prefix, n_shards, cfg.replicas)
+        keyspace = KeySpace(prefix, n_shards, cfg.replicas, pg.dtype)
 
         tracer = get_tracer()
         metrics = get_metrics()
@@ -512,21 +511,22 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
             for qi in range(q_count):
                 nodes = beam_safe[qi][valid_beam[qi]]
                 ids_list = [pag.node_src[nodes].astype(np.int64)]
-                vec_list = [pg.A[nodes].astype(np.float32)]
+                vec_list = [pg.A[nodes].astype(pg.dtype)]
                 for pid in pool_src[qi]:
                     obj = pool_objs.get(pid)
                     if obj is None:
                         continue
-                    ids_list.append(_unpack_ids(obj[:, 0]))
-                    vec_list.append(obj[:, 1:])
+                    ids, vecs = keyspace.unpack(obj)
+                    ids_list.append(ids)
+                    vec_list.append(vecs)
                 ids_cat = np.concatenate(ids_list)
                 keep = dedup_first(ids_cat)
                 pool_ids.append(ids_cat[keep])
                 pool_vecs.append(np.concatenate(vec_list)[keep])
-            sp.set(candidates=sum(map(len, pool_ids)))
+            sp.set(candidates=sum(map(len, pool_ids)),
+                   bytes=sum(v.nbytes for v in pool_vecs))
 
-        out_ids, out_d2 = scan.topk(queries.astype(np.float32), pool_ids,
-                                    pool_vecs, cfg.k)
+        out_ids, out_d2 = scan.topk(queries, pool_ids, pool_vecs, cfg.k)
 
         with host_span("search.stats"):
             stats = SearchStats([], [], [],

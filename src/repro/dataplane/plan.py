@@ -7,7 +7,8 @@ module owns the plan half:
 
 * ``KeySpace`` — the v2 storage layout as one value: logical partition
   id → replica key chains for the float residual / PQ code payloads,
-  plus the codebook keys. Built once per search call; every wave and
+  plus the codebook keys, and the row layout of the residual objects
+  (``pack`` / ``unpack``). Built once per search call; every wave and
   the prefetch pipeline derive their keys from it instead of
   re-deriving ``replica_keys`` call sites.
 
@@ -26,22 +27,65 @@ module owns the plan half:
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
 from repro.storage.resilience import codebook_keys, replica_keys
 
-PAYLOAD_FLOAT = "float"   # float residual objects (v1 / v2 exact path)
+PAYLOAD_FLOAT = "float"   # residual objects (v1 / v2 exact path)
 PAYLOAD_CODE = "code"     # uint8 PQ code objects (v2 compressed path)
+ID_BYTES = 4              # each residual row starts with its int32 id
+
+
+def pack_ids(ids: np.ndarray, dtype=np.float32) -> np.ndarray:
+    """Bit-cast int32 ids into the 4-byte id prefix of residual rows:
+    [cnt, 4 // itemsize] elements of ``dtype`` (one float32 column, four
+    uint8 columns). A value cast would only be exact below 2^24 in
+    float32 and below 2^8 in uint8; the bit-cast is exact for the whole
+    int32 range, so billion-scale ids survive storage round-trips."""
+    return np.ascontiguousarray(ids, np.int32).view(dtype).reshape(
+        len(ids), ID_BYTES // np.dtype(dtype).itemsize)
+
+
+def unpack_ids(prefix: np.ndarray) -> np.ndarray:
+    """Inverse of ``pack_ids``: id prefix (or a float32 id column) ->
+    int64 ids."""
+    return np.ascontiguousarray(prefix).view(np.int32).reshape(-1) \
+        .astype(np.int64)
 
 
 @dataclasses.dataclass(frozen=True)
 class KeySpace:
-    """Logical partition ids -> storage keys of the v2 payload layout."""
+    """Logical partition ids -> storage keys of the v2 payload layout,
+    and the rows of a residual object: a 4-byte int32 id followed by the
+    d elements of the vector, all in the base's ``dtype`` (float32:
+    ``[cnt, 1 + d]``, the id bit-cast into column 0; uint8 / int8:
+    ``[cnt, 4 + d]``, the id in the first four bytes)."""
     prefix: str = "part"
     n_shards: int = 1
     replicas: int = 1
+    dtype: str = "float32"      # element type of the residual rows
+
+    @property
+    def id_cols(self) -> int:
+        """Elements of a row that hold its id."""
+        return ID_BYTES // np.dtype(self.dtype).itemsize
+
+    def pack(self, ids: np.ndarray, vecs: np.ndarray) -> np.ndarray:
+        """One residual object: ids [cnt] and their vectors [cnt, d]
+        (of the payload's type where it is an integer one)."""
+        if self.dtype != "float32" and vecs.dtype != self.dtype:
+            raise ValueError(f"{vecs.dtype} rows for a {self.dtype} payload")
+        obj = np.empty((len(ids), self.id_cols + vecs.shape[1]), self.dtype)
+        obj[:, :self.id_cols] = pack_ids(ids, self.dtype)
+        obj[:, self.id_cols:] = vecs
+        return obj
+
+    def unpack(self, obj: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """(ids [cnt] int64, vectors [cnt, d] in the base's dtype) of a
+        residual object, the vectors a view of it."""
+        return unpack_ids(obj[:, :self.id_cols]), obj[:, self.id_cols:]
 
     def keys(self, pid: int, payload: str = PAYLOAD_FLOAT) -> List[str]:
         """Replica key chain (primary first) of one partition payload."""

@@ -9,8 +9,10 @@ this stage; nothing else in the tree calls ``kernels.ops`` for the query
 path. Each step is a host span (``anns/scan.*``); a launch span covers
 the host->device copy, the kernel and the pull of its result, with the
 bytes copied (``h2d_bytes``), the launch's slots (rows x pool width) and
-the slots that hold a real candidate (``filled``); the ADC pool span
-counts the rows pooled (``rows``) and those kept after dedup (``kept``).
+the slots that hold a real candidate (``filled``); the exact launch also
+gives the bytes of one pooled element (``itemsize``: the base's type,
+1 for uint8 / int8); the ADC pool span counts the rows pooled (``rows``)
+and those kept after dedup (``kept``).
 """
 from __future__ import annotations
 
@@ -137,7 +139,9 @@ class ScanStage:
              ) -> Tuple[np.ndarray, np.ndarray]:
         """One vectorized distance/top-k pass over every query's candidate
         pool (ragged rows padded with id -1), routed through the Pallas
-        masked l2_topk kernel. Returns (ids [Q, k] int64, d2 [Q, k])."""
+        masked l2_topk kernel. Queries and pools come, and are padded and
+        launched, in the base's element type (float32, uint8 or int8).
+        Returns (ids [Q, k] int64, d2 [Q, k])."""
         q_count, d = queries.shape
         c_max = max((len(p) for p in pool_ids), default=0)
         if c_max == 0:
@@ -145,10 +149,10 @@ class ScanStage:
                     np.full((q_count, k), INF, np.float32))
         rows, width = self._shape(q_count, c_max)
         with host_span("scan.topk_pad"):
-            q_pad = np.zeros((rows, d), np.float32)
+            q_pad = np.zeros((rows, d), queries.dtype)
             q_pad[:q_count] = queries
             ids_pad = np.full((rows, width), -1, np.int32)
-            vecs_pad = np.zeros((rows, width, d), np.float32)
+            vecs_pad = np.zeros((rows, width, d), queries.dtype)
             for qi in range(q_count):
                 n = len(pool_ids[qi])
                 if n:
@@ -157,7 +161,8 @@ class ScanStage:
         with host_span("scan.topk_launch",
                        h2d_bytes=q_pad.nbytes + vecs_pad.nbytes
                        + ids_pad.nbytes, slots=rows * width,
-                       filled=sum(map(len, pool_ids))):
+                       filled=sum(map(len, pool_ids)),
+                       itemsize=vecs_pad.itemsize):
             d2, ids = ops.l2_topk_masked(
                 jnp.asarray(q_pad), jnp.asarray(vecs_pad),
                 jnp.asarray(ids_pad), k=k, block_c=self.scan_block)

@@ -11,6 +11,12 @@ to HBM (the jnp path materializes the full [Q, N] matrix).
 Everything inside the kernels is 2-D and gather-free so that Mosaic (the
 TPU kernel compiler) accepts it: selection is a masked min per step, not
 an indexed read, and per-vector norms travel as [Q, 1] / [1, N] blocks.
+
+The masked kernel also takes 1-byte integer pools and queries (uint8,
+int8: BIGANN-style bases), which reach it in their own type and are
+widened to float32 inside the kernel (``_widen``); every difference,
+square and partial sum is then an integer below 2**24 up to d = 129
+(uint8), so the distances are exact.
 """
 from __future__ import annotations
 
@@ -122,13 +128,21 @@ def l2_topk(q: jax.Array, x: jax.Array, k: int = 10,
     return out_d, out_i
 
 
+def _widen(x):
+    """A block in float32. Mosaic casts no unsigned type to a float
+    directly, so unsigned integers go through int32 (exact)."""
+    if jnp.issubdtype(x.dtype, jnp.unsignedinteger):
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
 def _masked_kernel(q_ref, x_ref, id_ref, out_d_ref, out_i_ref, *, k: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_d_ref[...] = jnp.full_like(out_d_ref, 3.4e38)
         out_i_ref[...] = jnp.full_like(out_i_ref, -1)
 
-    q = q_ref[...].astype(jnp.float32)            # [BQ, d] query tile
+    q = _widen(q_ref[...])                        # [BQ, d] query tile
     ids = id_ref[...]                             # [BQ, BC] (-1 = padding)
     bq, bc = ids.shape
     row = jax.lax.broadcasted_iota(jnp.int32, (bq, bc), 0)
@@ -137,7 +151,7 @@ def _masked_kernel(q_ref, x_ref, id_ref, out_d_ref, out_i_ref, *, k: int):
     for r in range(bq):
         # query r's pool block against query r: the ones-matmul sums the
         # squared differences over d AND lays the result along lanes
-        diff = x_ref[r].astype(jnp.float32) - q[r:r + 1, :]   # [BC, d]
+        diff = _widen(x_ref[r]) - q[r:r + 1, :]               # [BC, d]
         d2_r = jax.lax.dot_general(
             ones, diff * diff, (((1,), (1,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST,
@@ -155,8 +169,9 @@ def l2_topk_masked(q: jax.Array, pools: jax.Array, ids: jax.Array,
                    k: int = 10, block_c: int = 256, *, interpret: bool):
     """Ragged per-query candidate pools -> per-query top-k.
 
-    q [Q, d]; pools [Q, C, d] (row c of query i = candidate vector);
-    ids [Q, C] int32 candidate ids with -1 marking ragged padding.
+    q [Q, d]; pools [Q, C, d] (row c of query i = candidate vector),
+    both float32, uint8 or int8; ids [Q, C] int32 candidate ids with -1
+    marking ragged padding.
     Returns (d2 [Q, k] ascending, ids [Q, k]); rows shorter than k are
     padded with (3.4e38, -1). One kernel launch scans the pools of ALL
     queries of a batch (the batched-search hot loop), tiled BLOCK_Q

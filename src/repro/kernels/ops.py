@@ -30,8 +30,11 @@ def l2_topk(q, x, k: int = 10, block_n: int = 512,
 
 def l2_topk_masked(q, pools, ids, k: int = 10, block_c: int = 256,
                    interpret: bool | None = None):
-    """q [Q, d], pools [Q, C, d], ids [Q, C] (-1 pads ragged rows)
-    -> (d2 [Q, k] ascending, ids [Q, k]); short rows pad (3.4e38, -1)."""
+    """q [Q, d], pools [Q, C, d] (one dtype: float32, uint8 or int8),
+    ids [Q, C] (-1 pads ragged rows) -> (d2 [Q, k] ascending, ids
+    [Q, k]); short rows pad (3.4e38, -1). Integer distances are exact."""
+    if q.dtype != pools.dtype:
+        raise TypeError(f"{q.dtype} queries against {pools.dtype} pools")
     interpret = default_interpret() if interpret is None else interpret
     return _l2.l2_topk_masked(q, pools, ids, k=k, block_c=block_c,
                               interpret=interpret)

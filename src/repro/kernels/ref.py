@@ -19,13 +19,20 @@ def l2_topk_ref(q: jax.Array, x: jax.Array, k: int):
 def l2_topk_masked_ref(q: jax.Array, pools: jax.Array, ids: jax.Array,
                        k: int):
     """q [Q, d]; pools [Q, C, d]; ids [Q, C] (-1 = padding) ->
-    (d2 [Q, k], ids [Q, k]) ascending; short rows pad with (3.4e38, -1)."""
-    q = q.astype(jnp.float32)
-    pools = pools.astype(jnp.float32)
-    d2 = (jnp.sum(q * q, -1)[:, None]
-          - 2 * jnp.einsum("qd,qcd->qc", q, pools)
-          + jnp.sum(pools * pools, -1))
-    d2 = jnp.maximum(d2, 0.0)
+    (d2 [Q, k], ids [Q, k]) ascending; short rows pad with (3.4e38, -1).
+    Integer pools and queries are compared exactly: differences and
+    squares in int32, the integer distance then in float32 (exact below
+    2**24, so up to d = 129 for uint8)."""
+    if jnp.issubdtype(pools.dtype, jnp.integer):
+        diff = pools.astype(jnp.int32) - q.astype(jnp.int32)[:, None, :]
+        d2 = jnp.sum(diff * diff, -1).astype(jnp.float32)
+    else:
+        q = q.astype(jnp.float32)
+        pools = pools.astype(jnp.float32)
+        d2 = (jnp.sum(q * q, -1)[:, None]
+              - 2 * jnp.einsum("qd,qcd->qc", q, pools)
+              + jnp.sum(pools * pools, -1))
+        d2 = jnp.maximum(d2, 0.0)
     d2 = jnp.where(ids >= 0, d2, 3.4e38)
     c = pools.shape[1]
     if c < k:  # pad so top_k has k columns to select from

@@ -177,9 +177,10 @@ class ObjectStore:
             h = zlib.crc32(f"{self.fault_plan.seed}:flip:{key}".encode())
             flat = bad.reshape(-1)
             if np.issubdtype(bad.dtype, np.integer):
-                # integer payloads (PQ code objects): XOR a nonzero
-                # pattern — always changes the element, never overflows
-                flat[h % bad.size] ^= np.asarray(0xA5, bad.dtype)
+                # integer payloads (PQ codes, uint8 / int8 residuals): XOR
+                # the bit pattern 0xA5 (wrapped into signed types) —
+                # always changes the element, never overflows
+                flat[h % bad.size] ^= np.asarray(0xA5).astype(bad.dtype)
             else:
                 # finite garbage: wrong enough to poison ids/distances,
                 # still castable (no overflow warnings downstream)

@@ -20,6 +20,7 @@ from repro.kernels import pq_adc as pq_kernels
 # rows of the PAG graph arena (aggregation points + promotions + slack)
 # that chip_smoke.py's build produces at its default n
 GRAPH_ROWS = 160_000
+BIGANN_D = 128      # SIFT-1B's width (uint8)
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +65,30 @@ def test_pq_adc_masked_compiles(smoke, one_chip):
                         s((smoke.BATCH, c, smoke.PQ_M), jnp.uint8),
                         s((smoke.BATCH, c), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.int8])
+def test_l2_topk_masked_compiles_on_bigann_vectors(smoke, one_chip, dtype):
+    """1-byte pools and queries at BIGANN's width reach Mosaic in their
+    own type (the kernel widens them inside)."""
+    c = _pool_width(smoke, pq=False)
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn = jax.jit(functools.partial(l2_kernels.l2_topk_masked, k=smoke.K,
+                                   block_c=256, interpret=False))
+    compiled = fn.lower(s((smoke.BATCH, BIGANN_D), dtype),
+                        s((smoke.BATCH, c, BIGANN_D), dtype),
+                        s((smoke.BATCH, c), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_greedy_search_compiles_on_a_uint8_graph(smoke, one_chip):
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    L = smoke.SEARCH["L"]
+    greedy_search.lower(
+        s((GRAPH_ROWS, BIGANN_D), jnp.uint8),
+        s((GRAPH_ROWS, 18), jnp.int32),
+        s((), jnp.int32), s((), jnp.int32),
+        s((smoke.BATCH, BIGANN_D), jnp.uint8), L=L, K=L).compile()
 
 
 def test_greedy_search_compiles(smoke, one_chip):
