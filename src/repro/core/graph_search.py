@@ -32,17 +32,48 @@ class SearchResult(NamedTuple):
 
 
 def _merge_beam(c_ids, c_d, c_exp, new_ids, new_d, L):
-    """Merge candidates, dedup, keep best L by distance."""
-    ids = jnp.concatenate([c_ids, new_ids])
-    ds = jnp.concatenate([c_d, new_d])
-    exp = jnp.concatenate([c_exp, jnp.zeros(new_ids.shape, bool)])
-    # dedup: mark later duplicates as INF
-    order = jnp.argsort(ids)
-    sid = ids[order]
-    dup = jnp.concatenate([jnp.zeros(1, bool), sid[1:] == sid[:-1]])
-    ds = ds.at[order].set(jnp.where(dup, INF, ds[order]))
-    keep = jnp.argsort(ds)[:L]
-    return ids[keep], ds[keep], exp[keep]
+    """Merge the R new candidates into the beam and keep the best L.
+
+    Invariant: the beam [L] is sorted ascending by distance, ties in the
+    order a stable sort of (beam ++ new) by distance gives. So the merge
+    is that stable sort, and each entry's place in it is a count:
+
+    - beam entry i goes to ``i + #{j : nd_j < d_i}``;
+    - new entry j goes to ``#{i : d_i <= nd_j}`` plus the new entries
+      before it (``nd_j' < nd_j``, or equal with ``j' < j``).
+
+    Dedup is needed only inside the new row: every scored node is marked
+    visited, so a candidate already in the beam scores INF here, and a
+    repeat of an earlier id in the row gets INF. Slot p of the new beam
+    holds new entry j where its place is p, else beam entry p - k_p, where
+    k_p counts the new entries placed before p: one of R + 1 static
+    shifts of the beam. No sort is needed."""
+    R = new_ids.shape[0]
+    j = jnp.arange(R)
+    earlier = j[None, :] < j[:, None]                      # [R, R]: j' < j
+    repeat = jnp.any((new_ids[None, :] == new_ids[:, None]) & earlier, 1)
+    nd = jnp.where(repeat, INF, new_d)
+    ahead = (nd[None, :] < nd[:, None]) | ((nd[None, :] == nd[:, None])
+                                           & earlier)
+    pos_n = (jnp.sum(c_d[None, :] <= nd[:, None], 1)
+             + jnp.sum(ahead, 1))                          # [R]
+    p = jnp.arange(L)
+    hit = pos_n[None, :] == p[:, None]                     # [L, R]
+    is_new = jnp.any(hit, 1)
+    k = jnp.sum(pos_n[None, :] < p[:, None], 1)            # [L]
+
+    def place(beam, new, fill):
+        out = beam
+        for s in range(1, min(R, L - 1) + 1):   # k_p <= p < L
+            shifted = jnp.concatenate([jnp.full((s,), fill, beam.dtype),
+                                       beam[:L - s]])
+            out = jnp.where(k == s, shifted, out)
+        return jnp.where(is_new, jnp.max(jnp.where(hit, new[None, :], fill),
+                                         1), out)
+
+    return (place(c_ids, new_ids, jnp.iinfo(jnp.int32).min),
+            place(c_d, nd, -jnp.inf),
+            place(c_exp, jnp.zeros((R,), bool), False))
 
 
 @functools.partial(jax.jit, static_argnames=("L", "K", "max_hops"))
@@ -97,8 +128,8 @@ def greedy_search(A, nbrs, n_nodes, entry, queries, *, L: int = 64,
         c_ids, c_d, c_exp, visited, path, path_d, hops = \
             jax.lax.while_loop(cond, body, state)
 
-        order = jnp.argsort(c_d)[:K]
-        return SearchResult(c_ids[order], c_d[order], path, path_d, hops)
+        # the beam is sorted: its first K are the K nearest
+        return SearchResult(c_ids[:K], c_d[:K], path, path_d, hops)
 
     return jax.vmap(one)(queries, entries)
 

@@ -365,6 +365,8 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
             hops = np.asarray(res.n_hops)[:q_count]
             beam_ids = np.asarray(res.ids)[:q_count]
             beam_d2 = np.asarray(res.dists)[:q_count]
+            # the beam merges once a hop: device ms / hops_max is a hop's cost
+            sp.set(hops_max=int(hops.max(initial=0)), hops_sum=int(hops.sum()))
 
         with host_span("search.app_replay") as sp:
             R_edges = pg.nbrs.shape[1]

@@ -486,3 +486,21 @@ def test_openmetrics_roundtrip():
         assert v == snap[f"frontend.batch-size.le_{b:g}"]
         acc.append(v)
     assert acc == sorted(acc)
+
+
+def test_graph_span_counts_the_hops(built_pag, small_ds):
+    """``anns/graph`` carries the hops of the batch's slowest query and
+    their sum, as ``greedy_search`` reports them for the real rows."""
+    import jax.numpy as jnp
+
+    from repro.core.graph_search import greedy_search
+    tr = Tracer()
+    with observe(tracer=tr):
+        _search(built_pag, small_ds, _mk_store(built_pag, small_ds))
+    (graph,) = [s for s in tr.spans if s.name == "anns/graph"]
+    A, nbrs, n_nodes, entry = built_pag.pg.device_arrays()
+    hops = np.asarray(greedy_search(
+        A, nbrs, n_nodes, entry, jnp.asarray(small_ds.queries[:16]),
+        L=32, K=32).n_hops)
+    assert graph.args["hops_max"] == int(hops.max()) > 1
+    assert graph.args["hops_sum"] == int(hops.sum())

@@ -94,10 +94,13 @@ def test_greedy_search_compiles_on_a_uint8_graph(smoke, one_chip):
 def test_greedy_search_compiles(smoke, one_chip):
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     L = smoke.SEARCH["L"]
-    compiled = greedy_search.lower(
+    lowered = greedy_search.lower(
         s((GRAPH_ROWS, smoke.D), jnp.float32),
         s((GRAPH_ROWS, 18), jnp.int32),
         s((), jnp.int32), s((), jnp.int32),
-        s((smoke.BATCH, smoke.D), jnp.float32), L=L, K=L).compile()
+        s((smoke.BATCH, smoke.D), jnp.float32), L=L, K=L)
+    # the beam is merged by counting ranks: no sort in any hop
+    assert "stablehlo.sort" not in lowered.as_text()
+    compiled = lowered.compile()
     # the whole graph phase fits one v5e's 16 GB of HBM with room to spare
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
