@@ -4,14 +4,10 @@ disk  -> Fig 8 (disk-memory hybrid)
 mem   -> Fig 9 (in-memory; HNSW joins)
 dfs   -> Fig 10 (DFS-memory hybrid; the paper's headline scenario)
 
-PAG is reported through both data-plane engines: "PAG" is the batched
-engine (cross-query coalesced fetches, batch event clock -> batch_qps),
-"PAG-seq" is the seed per-query loop (serial stream). Same probes and
-identical results by construction; the QPS gap is the batching win.
+"PAG" is the batch-coalesced data plane (cross-query coalesced fetches,
+batch event clock -> batch_qps); the times are modelled, not measured.
 """
 from __future__ import annotations
-
-import dataclasses
 
 import numpy as np
 
@@ -40,17 +36,9 @@ def _curves(ctx: BenchContext, storage: str, k: int = 10):
                                 n_shards=N_SHARDS)
         rec = recall_at_k(ids, ds.gt_ids, k)
         rows.append(("PAG", f"L{L}/p{npb}", rec, st.batch_qps()))
-
-        store = ctx.pag_store("clustered", storage, pag, seed=1)
-        cfg_seq = dataclasses.replace(cfg, engine="per_query")
-        ids_s, _, st_s = search_pag(pag, ds.d, ds.queries, store, cfg_seq,
-                                    n_shards=N_SHARDS)
-        rec_s = recall_at_k(ids_s, ds.gt_ids, k)
-        rows.append(("PAG-seq", f"L{L}/p{npb}", rec_s, st_s.batch_qps()))
-        speedup = st.batch_qps() / max(st_s.batch_qps(), 1e-9)
         dedup = st.n_distinct_fetches / max(sum(st.n_probes), 1)
-        emit(f"qps_recall/{storage}/batched_speedup/L{L}p{npb}", 0.0,
-             f"speedup={speedup:.2f};distinct_frac={dedup:.3f};"
+        emit(f"qps_recall/{storage}/coalescing/L{L}p{npb}", 0.0,
+             f"distinct_frac={dedup:.3f};"
              f"fetches={st.n_distinct_fetches};probes={sum(st.n_probes)}")
 
     dk, dk_store, _ = ctx.diskann("clustered", storage)
@@ -80,8 +68,8 @@ INFLIGHT_SWEEP = (1, 2, 4, 8, 16, 32, 64, None)
 
 def _inflight_saturation(ctx: BenchContext, storage: str = "dfs",
                          k: int = 10):
-    """Bounded fetch concurrency: where does the batched engine's RPC
-    wave saturate? max_inflight=1 degenerates to a serial fetch stream;
+    """Bounded fetch concurrency: where does the coalesced RPC wave
+    saturate? max_inflight=1 degenerates to a serial fetch stream;
     None is the unlimited wave the simulator modeled before."""
     ds = ctx.dataset("clustered")
     pag, _ = ctx.pag("clustered", p=0.2, lam=3.0, redundancy=4)
@@ -119,9 +107,8 @@ def pq_main(ctx: BenchContext):
     the probe wave covers many partitions whose codes are ~32x smaller
     than the residuals, while the exact refine wave concentrates in the
     few partitions covering the ADC top — the geometry where the paper's
-    DFS byte bill actually shrinks. bytes/query is reported from the
-    per_query engine (no cross-query coalescing amortizing the bill) and
-    QPS from the batched engine."""
+    DFS byte bill actually shrinks. bytes/query counts each coalesced
+    fetch once, so shared partitions amortize over their probers."""
     from repro.core.pag import build_pag
     from repro.data.vectors import brute_force_knn
     from repro.storage.cache import PartitionCache
@@ -155,36 +142,28 @@ def pq_main(ctx: BenchContext):
         return recall_at_k(ids, gt_ids, k), by, st
 
     print("\n== compressed data plane: PQ codes + exact rerank (dfs) ==")
-    base_bytes = {}
-    for engine in ("per_query", "batched"):
-        rec, by, st = run(SearchConfig(k=k, n_probe_max=32,
-                                       engine=engine))
-        base_bytes[engine] = by
-        print(f"  float {engine:9s}          recall={rec:.3f} "
-              f"bytes/q={by:9.0f} batch_qps={st.batch_qps():8.0f} "
-              f"p99={st.p99()*1e3:.2f}ms")
-        emit(f"qps_recall/pq/float/{engine}", 1e6 / st.batch_qps(),
-             f"recall={rec:.3f};bytes_per_q={by:.0f};"
-             f"batch_qps={st.batch_qps():.0f};p99_ms={st.p99()*1e3:.3f}")
+    rec, base_bytes, st = run(SearchConfig(k=k, n_probe_max=32))
+    print(f"  float         recall={rec:.3f} "
+          f"bytes/q={base_bytes:9.0f} batch_qps={st.batch_qps():8.0f} "
+          f"p99={st.p99()*1e3:.2f}ms")
+    emit("qps_recall/pq/float", 1e6 / st.batch_qps(),
+         f"recall={rec:.3f};bytes_per_q={base_bytes:.0f};"
+         f"batch_qps={st.batch_qps():.0f};p99_ms={st.p99()*1e3:.3f}")
     for rk in rerank_sweep:
-        for engine in ("per_query", "batched"):
-            rec, by, st = run(SearchConfig(k=k, n_probe_max=32,
-                                           engine=engine,
-                                           compression="pq",
-                                           rerank_k=rk))
-            ratio = base_bytes[engine] / max(by, 1e-9)
-            print(f"  pq rk={rk:3d} {engine:9s}    recall={rec:.3f} "
-                  f"bytes/q={by:9.0f} batch_qps={st.batch_qps():8.0f} "
-                  f"p99={st.p99()*1e3:.2f}ms ratio={ratio:.2f}x")
-            emit(f"qps_recall/pq/rk{rk}/{engine}", 1e6 / st.batch_qps(),
-                 f"recall={rec:.3f};bytes_per_q={by:.0f};"
-                 f"batch_qps={st.batch_qps():.0f};"
-                 f"p99_ms={st.p99()*1e3:.3f};bytes_ratio={ratio:.2f}")
-            if engine == "per_query" and rk == max(rerank_sweep):
-                emit("qps_recall/pq/acceptance", 0.0,
-                     f"bytes_ratio={ratio:.2f};recall={rec:.3f}")
-                print(f"  >> bytes/query cut {ratio:.1f}x vs float "
-                      f"plane at recall={rec:.3f}")
+        rec, by, st = run(SearchConfig(k=k, n_probe_max=32,
+                                       compression="pq", rerank_k=rk))
+        ratio = base_bytes / max(by, 1e-9)
+        print(f"  pq rk={rk:3d}    recall={rec:.3f} "
+              f"bytes/q={by:9.0f} batch_qps={st.batch_qps():8.0f} "
+              f"p99={st.p99()*1e3:.2f}ms ratio={ratio:.2f}x")
+        emit(f"qps_recall/pq/rk{rk}", 1e6 / st.batch_qps(),
+             f"recall={rec:.3f};bytes_per_q={by:.0f};"
+             f"batch_qps={st.batch_qps():.0f};"
+             f"p99_ms={st.p99()*1e3:.3f};bytes_ratio={ratio:.2f}")
+    emit("qps_recall/pq/acceptance", 0.0,
+         f"bytes_ratio={ratio:.2f};recall={rec:.3f}")
+    print(f"  >> bytes/query cut {ratio:.1f}x vs float "
+          f"plane at recall={rec:.3f}")
 
     # compressed objects through the PartitionCache: same byte budget
     # now holds ~32x more partitions; report hit rate + evictions

@@ -1,24 +1,24 @@
-"""Distributed serving of the PAG index (DESIGN.md §6).
+"""Distributed serving of the PAG index.
 
 * ShardedServing: partitions round-robined over shards; the replicated
-  in-memory PG routes queries; queries go through the BATCHED data plane
-  (core/search.py: cross-query coalesced get_many fetches, one Pallas
-  pool scan per batch) unless cfg.engine overrides it. Shard failure ->
+  in-memory PG routes queries; queries go through the batch-coalesced
+  data plane (core/search.py: cross-query coalesced get_many fetches,
+  one Pallas pool scan per batch). Shard failure ->
   the router drops that shard's partitions (bounded recall degradation,
   tests/test_fault_tolerance.py); stragglers tamed by hedged duplicate
   fetches.
 
 * anns_serve_step / anns_build_assign_step: the jax-native pod-scale data
   plane, written with shard_map over the production mesh — these are the
-  ops the multi-pod dry-run lowers for the paper's own system (the `anns`
-  rows of EXPERIMENTS.md §Dry-run). The `data` axis shards the residual
-  database; the `model` axis replicates query batches (replica
+  ops the multi-pod dry-run lowers for the paper's own system (the
+  ``anns-*`` cells of ``launch/dryrun.py``). The `data` axis shards the
+  residual database; the `model` axis replicates query batches (replica
   parallelism); the top-k merge is an all-gather of k-candidates.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Optional, Set
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +27,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.distances import cdist2
 from repro.core.pag import PAG
-from repro.core.search import SearchConfig, SearchStats, search_pag
+from repro.core.search import SearchConfig, search_pag
 from repro.storage.simulator import ComputeModel, ObjectStore
 
 
@@ -162,7 +162,7 @@ def make_anns_assign_step(mesh: Mesh, k: int = 8, row_chunk: int = 4096,
     The distance matrix is never materialized: rows and agg columns are
     double-chunked with a running top-k (the l2_topk kernel pattern at
     pod scale) — the naive [N_loc, m_loc] product was a 2.27 TB/device
-    temp at BigANN scale (EXPERIMENTS.md §Perf iteration A1)."""
+    temp at BigANN scale."""
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp_spec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
 

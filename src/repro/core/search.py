@@ -2,8 +2,8 @@
 Partition Probe early stop (§V-A) + asynchronous partition fetch (Alg 5).
 
 Execution = real computation (exact recall); time = storage-simulator
-event clock (see DESIGN.md §8). This module is the *orchestrator*: the
-data plane itself is the staged pipeline in ``repro.dataplane`` —
+event clock. This module is the *orchestrator*: the data plane itself is
+the staged pipeline in ``repro.dataplane`` —
 
     plan   (``FetchPlan`` over a ``KeySpace``: probe orders -> keys)
     waves  (``WaveScheduler``: every storage wave, every clock)
@@ -12,26 +12,20 @@ data plane itself is the staged pipeline in ``repro.dataplane`` —
 ``search_pag`` builds the plans and sequences the stages; it performs
 no storage GETs of its own.
 
-Two data-plane engines (``SearchConfig.engine``):
-
-* ``"batched"`` (default) — the batch-coalesced plane. The graph phase
-  runs for the whole query batch, then partition probes are coalesced
-  across queries: each distinct partition is fetched ONCE per batch
-  (``WaveScheduler.run_coalesced`` — one concurrent RPC wave, hedging
-  preserved), filled into the optional cache, and scanned for all
-  probing queries in a single vectorized distance/top-k pass. Per-query
-  latency accounting survives: each query's ``QueryTimeline`` carries
-  its own traversal compute and its own probes, with a shared fetch's
-  latency charged to every prober. Batch throughput
-  (``SearchStats.batch_qps``) comes from the scheduler's batch-level
-  event clock: fetches issue as their first prober's traversal retires,
-  coalesced scans amortize the per-partition dispatch overhead.
-
-* ``"per_query"`` — the seed data plane kept as reference/baseline
-  (``WaveScheduler.run_per_query``): a python loop issuing blocking (or
-  hedged) per-partition GETs per query. Same probes, same candidate
-  pools, same scan arithmetic ⇒ bit-identical results to the batched
-  engine (tested), only the simulated I/O schedule differs.
+The data plane is batch-coalesced. The graph phase runs for the whole
+query batch, then partition probes are coalesced across queries: each
+distinct partition is fetched ONCE per batch
+(``WaveScheduler.run_coalesced`` — one concurrent RPC wave, hedging
+preserved), filled into the optional cache, and scanned for all probing
+queries in a single vectorized distance/top-k pass. Per-query latency
+accounting survives: each query's ``QueryTimeline`` carries its own
+traversal compute and its own probes, with a shared fetch's latency
+charged to every prober. Batch throughput (``SearchStats.batch_qps``)
+comes from the scheduler's batch-level event clock: fetches issue as
+their first prober's traversal retires, coalesced scans amortize the
+per-partition dispatch overhead. Results are held to a plain numpy
+reference that reads the base array, not the store
+(``tests/reference_search.py``).
 
 ``SearchConfig`` knobs:
 
@@ -40,14 +34,13 @@ Two data-plane engines (``SearchConfig.engine``):
   baseline (all fetches awaited after traversal, scans back-to-back).
   Affects only the simulated clock, never the returned neighbors.
 * ``hedge_after_s`` — straggler mitigation: each GET is duplicated
-  after this many seconds and the minimum latency wins (applies to both
-  engines and to ``get_many``). ``None`` disables hedging.
+  after this many seconds and the minimum latency wins (also in
+  ``get_many``). ``None`` disables hedging.
 * ``cache`` — optional ``PartitionCache``. Lookups happen before any
-  storage GET; hits cost zero latency for every prober. In the batched
-  engine the cache is consulted once per distinct partition and filled
-  from the fetch wave; coalesced probers beyond the first are counted
-  as hits (see ``PartitionCache.account_shared``) so hit-rate stays
-  comparable with the per-query plane.
+  storage GET; hits cost zero latency for every prober. The cache is
+  consulted once per distinct partition and filled from the fetch wave;
+  coalesced probers beyond the first are counted as hits (see
+  ``PartitionCache.account_shared``).
 * ``scan_block`` — candidate-pool block size of the Pallas scan.
 * ``replicas`` / ``resilience`` — the fault-tolerance plane. With
   ``replicas=R`` partitions are stored R-way (``write_partitions``)
@@ -56,8 +49,8 @@ Two data-plane engines (``SearchConfig.engine``):
   failover + circuit-breaker chain whose full event-clock cost is
   charged to the query timeline. Per-query damage is reported in
   ``SearchStats.degraded``.
-* ``max_inflight`` — bounds the concurrency of the batched engine's
-  RPC wave (sub-waves on the event clock; queueing charged).
+* ``max_inflight`` — bounds the concurrency of the coalesced RPC
+  wave (sub-waves on the event clock; queueing charged).
 * ``compression`` — ``"pq"`` switches the probe wave to the v2
   compressed payloads: the wave fetches only the per-partition PQ code
   objects, one masked Pallas ADC launch scores every query's pooled
@@ -70,7 +63,7 @@ Two data-plane engines (``SearchConfig.engine``):
 
 Prefetch-ahead (cross-batch pipelining, see ``dataplane.prefetch``):
 ``prefetch_probes`` hands ``search_pag`` the predicted probe orders of
-the NEXT micro-batch; the batched engine issues that wave's payload
+the NEXT micro-batch; ``search_pag`` issues that wave's payload
 objects at the event-clock point where this batch enters its
 refine/scan stages and returns the in-flight wave as
 ``SearchStats.prefetch``. The next call consumes it via ``prefetched``
@@ -88,7 +81,7 @@ kills both), carry put-time checksums, and replicate round-robin like
 the float path. Ids are NOT stored in code objects — the in-memory
 ``pag.plist`` already maps partition rows to original ids. A residual
 object (``KeySpace.pack``) keeps the base's element type: each row is a
-4-byte int32 id, bit-cast (``_pack_ids``/``_unpack_ids``, exact for
+4-byte int32 id, bit-cast (``pack_ids``/``unpack_ids``, exact for
 billion-scale ids), then the vector. A float32 row is ``[1 + d]``
 float32 (the id in column 0); a uint8 / int8 row (BIGANN-style bases)
 is ``[4 + d]`` bytes, and such a base stays in its type from the
@@ -110,10 +103,7 @@ from repro.dataplane.plan import (
     PAYLOAD_FLOAT,
     FetchPlan,
     KeySpace,
-    app_probe_order as _app_probe_order_impl,
-    pack_ids,
     probe_orders,
-    unpack_ids,
 )
 from repro.dataplane.prefetch import PrefetchHandle
 from repro.dataplane.scan import ID_SENTINEL, INF, ScanStage, dedup_first
@@ -126,15 +116,7 @@ from repro.storage.simulator import (
     ComputeModel,
     ObjectStore,
     QueryTimeline,
-    StorageConfig,
 )
-
-# moved into the dataplane package; re-bound here for callers/tests that
-# pin the historical import site (repro.core.search)
-_dedup_first = dedup_first
-_app_probe_order = _app_probe_order_impl
-_pack_ids = pack_ids
-_unpack_ids = unpack_ids
 
 __all__ = [
     "ID_SENTINEL", "INF", "DegradedInfo", "SearchConfig", "SearchStats",
@@ -149,10 +131,10 @@ def write_partitions(pag: PAG, x: np.ndarray, store: ObjectStore,
     """Materialize per-partition residual objects in the storage layer.
 
     Object = rows of the original id (a BIT-CAST int32, exact for all
-    ids — see ``_pack_ids``) and the vector, in the base's element type
-    (``KeySpace.pack``): float32 [cnt, 1 + d] for float bases, uint8 /
-    int8 [cnt, 4 + d] for 1-byte integer bases. Partitions are
-    round-robined over ``n_shards`` logical
+    ids — see ``dataplane.plan.pack_ids``) and the vector, in the base's
+    element type (``KeySpace.pack``): float32 [cnt, 1 + d] for float
+    bases, uint8 / int8 [cnt, 4 + d] for 1-byte integer bases.
+    Partitions are round-robined over ``n_shards`` logical
     shards (prefix/<shard>/<pid>) so failure injection can kill a shard
     (fault-tolerance tests). ``replicas=R`` writes R copies per
     partition: the primary under the legacy key and replica j under
@@ -204,7 +186,6 @@ class SearchConfig:
     rho: float = 1.25           # APP scale factor (paper's ρ)
     n_probe_max: int = 16       # cap on fetched partitions
     mode: str = "async"         # async | sync (Alg 5 vs blocking)
-    engine: str = "batched"     # batched | per_query (data plane)
     hedge_after_s: Optional[float] = None  # straggler mitigation
     cache: Optional[object] = None  # PartitionCache (beyond-paper, §V-B)
     scan_block: int = 256       # Pallas pool-scan block size
@@ -306,8 +287,7 @@ class SearchStats:
         return float(1.0 / np.maximum(lat.mean(), 1e-12))
 
     def batch_qps(self) -> float:
-        """Throughput of the whole batch on the simulated event clock
-        (per_query engine: serial stream, span = sum of latencies)."""
+        """Throughput of the whole batch on the simulated event clock."""
         return float(len(self.latencies_s)
                      / max(self.batch_span_s, 1e-12))
 
@@ -333,8 +313,8 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
     ``prefetched`` / ``prefetch_probes`` / ``trace_t0_s`` / ``pad_rows``
     serve the micro-batch pipeline (``serving.engine.AnnsFrontend``):
     objects the previous batch already fetched (key -> (object, residual
-    latency)), the predicted probe orders of the next batch (the batched
-    engine issues their wave mid-batch and returns it as
+    latency)), the predicted probe orders of the next batch (their wave
+    is issued mid-batch and returned as
     ``stats.prefetch``), the absolute event-clock offset of this batch's
     span tree (so frontend and batch tracks share one clock in the
     trace), and the row count every device launch is padded to (a short
@@ -426,11 +406,9 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
 
         fobjs: Dict[int, np.ndarray] = {}
         refine_all: List[List[int]] = [[] for _ in range(q_count)]
-        handle: Optional[PrefetchHandle] = None
-        batch_span: Optional[float] = None
 
         def finish_batch(t_prefetch: float):
-            """The batched engine's last wave ends here: issue the NEXT
+            """The batch's last wave ends here: issue the NEXT
             micro-batch's probe wave (overlapping this batch's refine/scan
             tail on the event clock) and resolve the batch clock.
             Returns (prefetch handle or None, batch makespan)."""
@@ -441,58 +419,36 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
                                     t_issue_s=t_prefetch)
             return pf, sched.finish_batch(cfg.mode)
 
-        if cfg.engine == "batched":
-            plan = _plan(probes_all, keyspace, probe_payload)
-            with _wave_span("probe", store):
-                wave = sched.run_coalesced(plan, cache=cfg.cache)
-                sched.charge_queries(wave, probe_cost, kind=probe_kind)
-                # batch event clock: a fetch issues when its FIRST
-                # prober's traversal retires; one coalesced scan per
-                # distinct partition
-                sched.charge_batch_codebook(cb_lat)
-                sched.charge_batch_probe(wave, traversal_s, x_dim, pq,
-                                         probe_kind)
-                if not pq:
-                    # every traversal retired: the batch's last wave
-                    handle, batch_span = finish_batch(sched.bt.compute_s)
-            objs = wave.objs
-            if pq:
-                if codebook is not None and objs:
-                    refine_all = scan.adc_select(codebook, queries,
-                                                 probes_all, objs, pag,
-                                                 cfg.rerank_k)
-                # stage boundary: the exact refine wave can only issue
-                # after the ADC pass over the code objects has retired
-                sched.barrier(cfg.mode)
-                t_prefetch = sched.bt.compute_s  # refine stage starts here
-                fplan = _plan(refine_all, keyspace, PAYLOAD_FLOAT)
-                with _wave_span("refine", store):
-                    fwave = sched.run_coalesced(fplan, cache=None)
-                    sched.charge_queries(fwave, exact_cost, kind="exact")
-                    sched.charge_batch_refine(fwave, x_dim)
-                    handle, batch_span = finish_batch(t_prefetch)
-                fobjs = fwave.objs
-        elif cfg.engine == "per_query":
-            # seed data plane: blocking per-partition GETs, query by query
-            plan = _plan(probes_all, keyspace, probe_payload)
-            with _wave_span("probe", store):
-                objs, _ = sched.run_per_query(plan, cache=cfg.cache,
-                                              scan_cost=probe_cost,
-                                              kind=probe_kind)
-            if pq:
-                if codebook is not None and objs:
-                    refine_all = scan.adc_select(codebook, queries,
-                                                 probes_all, objs, pag,
-                                                 cfg.rerank_k)
-                sched.barrier(cfg.mode)  # ADC retires before the refine
-                fplan = _plan(refine_all, keyspace, PAYLOAD_FLOAT)
-                with _wave_span("refine", store):
-                    fobjs, _ = sched.run_per_query(fplan, cache=None,
-                                                   scan_cost=exact_cost,
-                                                   kind="exact")
-            # serial stream: batch_span is filled from latencies below
-        else:
-            raise ValueError(f"unknown engine: {cfg.engine!r}")
+        plan = _plan(probes_all, keyspace, probe_payload)
+        with _wave_span("probe", store):
+            wave = sched.run_coalesced(plan, cache=cfg.cache)
+            sched.charge_queries(wave, probe_cost, kind=probe_kind)
+            # batch event clock: a fetch issues when its FIRST
+            # prober's traversal retires; one coalesced scan per
+            # distinct partition
+            sched.charge_batch_codebook(cb_lat)
+            sched.charge_batch_probe(wave, traversal_s, x_dim, pq,
+                                     probe_kind)
+            if not pq:
+                # every traversal retired: the batch's last wave
+                handle, batch_span = finish_batch(sched.bt.compute_s)
+        objs = wave.objs
+        if pq:
+            if codebook is not None and objs:
+                refine_all = scan.adc_select(codebook, queries,
+                                             probes_all, objs, pag,
+                                             cfg.rerank_k)
+            # stage boundary: the exact refine wave can only issue
+            # after the ADC pass over the code objects has retired
+            sched.barrier(cfg.mode)
+            t_prefetch = sched.bt.compute_s  # refine stage starts here
+            fplan = _plan(refine_all, keyspace, PAYLOAD_FLOAT)
+            with _wave_span("refine", store):
+                fwave = sched.run_coalesced(fplan, cache=None)
+                sched.charge_queries(fwave, exact_cost, kind="exact")
+                sched.charge_batch_refine(fwave, x_dim)
+                handle, batch_span = finish_batch(t_prefetch)
+            fobjs = fwave.objs
 
         if sched.resilient is not None:
             n_open = sched.resilient.n_open_breakers()
@@ -547,8 +503,7 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
                 stats.n_probes.append(
                     sum(1 for pid in probes_all[qi] if pid in objs))
                 stats.n_hops.append(int(hops[qi]))
-            stats.batch_span_s = batch_span if batch_span is not None \
-                else float(np.sum(stats.latencies_s))
+            stats.batch_span_s = batch_span
             if metrics.enabled:
                 metrics.inc("search.batches")
                 metrics.inc("search.queries", q_count)
@@ -567,13 +522,10 @@ def search_pag(pag: PAG, x_dim: int, queries: np.ndarray,
             if rec:
                 from repro.obs.trace import emit_search_spans
                 stats.trace_group = emit_search_spans(
-                    tracer,
-                    batch_events=(sched.bt.events
-                                  if cfg.engine == "batched" else None),
+                    tracer, batch_events=sched.bt.events,
                     batch_span_s=stats.batch_span_s, timelines=timelines,
-                    latencies_s=stats.latencies_s, engine=cfg.engine,
-                    pq=pq, n_probes=stats.n_probes,
-                    t0_s=trace_t0_s) or ""
+                    latencies_s=stats.latencies_s, pq=pq,
+                    n_probes=stats.n_probes, t0_s=trace_t0_s) or ""
             return out_ids, out_d2, stats
 
 

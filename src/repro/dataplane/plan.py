@@ -1,6 +1,7 @@
 """Fetch planning for the staged query data plane.
 
-The data plane runs as a pipeline of stages (DESIGN.md §8, paper Alg 5):
+The data plane runs as a pipeline of stages (paper Alg 5; PERF.md §3
+names each stage's span):
 *plan* (graph frontier → partition probe orders), *fetch waves* (the
 ``WaveScheduler``), *scan* (the ``ScanStage`` Pallas launches). This
 module owns the plan half:
@@ -16,8 +17,8 @@ module owns the plan half:
   the per-query probe orders: the distinct partitions in first-probe
   order (the coalesced wave's issue order) and the probers of each
   partition (per-query charging + batched-scan amortization). The
-  batched probe wave, the per-query reference wave, the PQ probe wave,
-  and the exact refine wave all consume the same plan shape.
+  float probe wave, the PQ probe wave and the exact refine wave all
+  consume the same plan shape.
 
 * ``probe_orders`` / ``app_probe_order`` — the APP early-stop replay
   (§V-A) shared by ``search_pag`` and the prefetch predictor

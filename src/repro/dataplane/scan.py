@@ -4,15 +4,15 @@
 (exact distance/top-k over the pooled candidates) and ``pq_adc`` (ADC
 scoring of pooled PQ codes + cover-aware refine-partition selection) —
 behind one object that owns padding, id bookkeeping, and the dedup rule
-for redundant copies (Def 5). Both engines and the benchmarks go through
-this stage; nothing else in the tree calls ``kernels.ops`` for the query
-path. Each step is a host span (``anns/scan.*``); a launch span covers
-the host->device copy, the kernel and the pull of its result, with the
-bytes copied (``h2d_bytes``), the launch's slots (rows x pool width) and
-the slots that hold a real candidate (``filled``); the exact launch also
-gives the bytes of one pooled element (``itemsize``: the base's type,
-1 for uint8 / int8); the ADC pool span counts the rows pooled (``rows``)
-and those kept after dedup (``kept``).
+for redundant copies (Def 5). ``search_pag`` and the benchmarks go
+through this stage; nothing else in the tree calls ``kernels.ops`` for
+the query path. Each step is a host span (``anns/scan.*``); a launch
+span covers the host->device copy, the kernel and the pull of its
+result, with the bytes copied (``h2d_bytes``), the launch's slots (rows
+x pool width) and the slots that hold a real candidate (``filled``); the
+exact launch also gives the bytes of one pooled element (``itemsize``:
+the base's type, 1 for uint8 / int8); the ADC pool span counts the rows
+pooled (``rows``) and those kept after dedup (``kept``).
 """
 from __future__ import annotations
 

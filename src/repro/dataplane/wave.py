@@ -1,23 +1,23 @@
 """Wave execution for the staged data plane.
 
 ``WaveScheduler`` owns ALL storage-wave execution of one search call:
-the coalesced batched wave (``run_coalesced`` — one cache pass + one
-concurrent ``get_many`` / replicated-chain wave over a ``FetchPlan``),
-the seed per-query wave (``run_per_query`` — blocking per-partition
-GETs), the codebook metadata fetch, per-query timeline charging +
-``DegradedInfo`` accounting, the batch event clock (``bt``), cache
-admission, and prefetch-ahead (serving a wave from the previous batch's
+the coalesced wave (``run_coalesced`` — one cache pass + one concurrent
+``get_many`` / replicated-chain wave over a ``FetchPlan``), the codebook
+metadata fetch, per-query timeline charging + ``DegradedInfo``
+accounting, the batch event clock (``bt``), cache admission, and
+prefetch-ahead (serving a wave from the previous batch's
 ``PrefetchHandle`` and issuing the next batch's).
 
-``core.search`` holds NO storage calls of its own anymore: the probe
-wave, the PQ probe wave, the exact refine wave, and the per-query
-reference plane are all ``WaveScheduler`` methods over ``FetchPlan``s.
+``core.search`` holds no storage calls of its own: the probe wave, the
+PQ probe wave and the exact refine wave are all ``WaveScheduler``
+methods over ``FetchPlan``s.
 
-Bit-identity contract: with no prefetch state, every code path below
-performs the exact same store/cache calls in the exact same order as
-the pre-refactor ``core.search`` internals (the store's latency RNG
-advances per call, so call ORDER is part of the observable behavior —
-the equivalence tests pin it).
+Call-order contract: the store's latency RNG advances per call, so the
+ORDER of store and cache calls is part of the observable behaviour.
+Tracing and profiling, on or off, must not change it — every wave below
+issues the same calls in the same order either way
+(``test_tracing_disabled_is_bit_identical`` and
+``test_profiler_session_leaves_results_identical`` pin this).
 """
 from __future__ import annotations
 
@@ -90,7 +90,7 @@ class WaveScheduler:
         self.degraded = degraded
         self.compute = compute
         self.dead_shard_fallback = dead_shard_fallback
-        # batch event clock (the batched engine's makespan)
+        # batch event clock (the batch's makespan)
         self.bt = QueryTimeline(record=record)
         # key -> (verified object, residual latency) from the previous
         # micro-batch's prefetch wave (see dataplane.prefetch)
@@ -276,82 +276,15 @@ class WaveScheduler:
         self.bt.barrier(mode)
 
     def finish_batch(self, mode: str) -> float:
-        """Resolve the batch clock; the batched engine's makespan."""
+        """Resolve the batch clock; the batch's makespan."""
         return self.bt.finish_async() if mode == "async" \
             else self.bt.finish_sync()
-
-    # ---------------------------------------------------- per-query wave
-    def run_per_query(self, plan: FetchPlan, *, cache, scan_cost,
-                      kind: str = "scan") -> Tuple[Dict[int, np.ndarray],
-                                                   int]:
-        """The seed data plane, one wave: blocking per-partition GETs,
-        query by query (no cross-query coalescing — a partition probed
-        by two queries is fetched twice unless a cache or the prefetch
-        handle serves the second). Charges each query's timeline and
-        fills per-query ``DegradedInfo``. Returns (objs, n_store)."""
-        cfg = self.cfg
-        objs: Dict[int, np.ndarray] = {}
-        n_store = 0
-        for qi, probes in enumerate(plan.probes_all):
-            for pid in probes:
-                key = plan.key(pid)
-                oc = None
-                pf = self.prefetched.get(key)
-                cached = None if pf is not None else \
-                    (cache.get(key) if cache is not None else None)
-                if pf is not None:
-                    obj, io_lat = pf   # residual latency only
-                    label = f"pfhit p{pid}"
-                    self.n_prefetch_hits += 1
-                    if cache is not None:  # verified at prefetch time
-                        cache.put(key, obj)
-                elif cached is not None:
-                    obj, io_lat = cached, 0.0  # local-memory hit
-                    label = f"hit p{pid}"
-                elif self.resilient is not None:
-                    oc = self.resilient.get_replicated(
-                        plan.rkeys(pid), hedge_after_s=cfg.hedge_after_s)
-                    self.degraded[qi].add_outcome(oc)
-                    if not oc.ok:
-                        self.degraded[qi].n_probes_lost += 1
-                        self.timelines[qi].issue_io(
-                            oc.elapsed_s, 0.0, label=f"lost p{pid}",
-                            detail=oc)
-                        if self.dead_shard_fallback:
-                            continue  # degraded: budget burned, no data
-                        raise KeyError(f"partition lost: {key}")
-                    obj, io_lat = oc.value, oc.elapsed_s
-                    label = f"{kind} p{pid}"
-                    n_store += 1
-                    if cache is not None:
-                        cache.put(key, obj)
-                else:
-                    try:
-                        if cfg.hedge_after_s is not None:
-                            obj, io_lat = self.store.get_hedged(
-                                key, cfg.hedge_after_s)
-                        else:
-                            obj, io_lat = self.store.get(key)
-                    except KeyError:
-                        self.degraded[qi].n_probes_lost += 1
-                        if self.dead_shard_fallback:
-                            continue  # degraded: skip dead partition
-                        raise
-                    label = f"{kind} p{pid}"
-                    n_store += 1
-                    if cache is not None and self.store.verify(key, obj):
-                        cache.put(key, obj)  # no corrupt admission
-                objs[pid] = obj
-                self.timelines[qi].issue_io(io_lat, scan_cost(obj),
-                                            label=label, detail=oc)
-        self.n_store += n_store
-        return objs, n_store
 
     # ------------------------------------------------- metadata (pq)
     def load_codebook(self, keyspace: KeySpace, *, cache):
         """Fetch the per-index PQ codebook object — index metadata shared
-        by every query, fetched once per search call in BOTH engines and
-        admitted to the cache (steady-state serving pays for it once).
+        by every query, fetched once per search call and admitted to the
+        cache (steady-state serving pays for it once).
         Returns (PQCodebook | None, latency_s, outcome)."""
         from repro.baselines.pq import PQCodebook
         cfg = self.cfg

@@ -1,9 +1,9 @@
 """Observability plane: span tracing + metrics on the simulated event
-clock (DESIGN.md §8), with a zero-cost no-op default, and named host
+clock, with a zero-cost no-op default, and named host
 spans of the served path on the profiler's clock (``host_span``).
 
-The data plane (storage simulator, resilience chains, cache, both
-search engines, the serving front-end) reports into whatever tracer /
+The data plane (storage simulator, resilience chains, cache,
+``search_pag``, the serving front-end) reports into whatever tracer /
 metrics registry is *currently installed*:
 
     from repro.obs import observe, Tracer, MetricsRegistry

@@ -69,18 +69,13 @@ def batch_breakdown(tracer: Tracer, root: Span) -> str:
             if s.track == root.track and s is not root]
     tile = _tile_durs(tracer, root)
     total = root.dur_s or 1.0
-    covered = sum(tile.values())
     args = root.args or {}
-    head = (f"{root.track}: {root.name} engine={args.get('engine', '?')}"
-            f" pq={args.get('pq', '?')}  span {_fmt_s(root.dur_s).strip()}")
+    head = (f"{root.track}: {root.name} pq={args.get('pq', '?')}"
+            f"  span {_fmt_s(root.dur_s).strip()}")
     lines = [head]
     for cat in _TILE_CATS:
         lines.append(f"  {_CAT_LABEL[cat]:<12}{_fmt_s(tile[cat])}"
                      f"  {100.0 * tile[cat] / total:5.1f}%")
-    slack = root.dur_s - covered
-    if slack > 1e-12:  # untiled remainder (per_query idle tail etc.)
-        lines.append(f"  {'other':<12}{_fmt_s(slack)}"
-                     f"  {100.0 * slack / total:5.1f}%")
     stages = [s for s in kids if s.ph == "b" and s.cat == "stage"]
     for s in sorted(stages, key=lambda s: s.t0_s):
         lines.append(f"  ~ {s.name:<12}{_fmt_s(s.dur_s)}"

@@ -291,14 +291,12 @@ def _stage_extent(events, kind: str, stage: int):
 
 
 def emit_search_spans(tracer: Tracer, *, batch_events, batch_span_s: float,
-                      timelines, latencies_s, engine: str, pq: bool,
-                      n_probes=None, group: Optional[str] = None,
-                      t0_s: float = 0.0) -> str:
+                      timelines, latencies_s, pq: bool, n_probes=None,
+                      group: Optional[str] = None, t0_s: float = 0.0) -> str:
     """Emit one ``search_pag`` call as a span tree.
 
     * a batch track: root ``batch`` span of exactly ``batch_span_s``,
-      compute/stall/scan children from the batch event clock (batched
-      engine) or serialized per-query slices (per_query engine), plus
+      compute/stall/scan children from the batch event clock, plus
       ``fetch_wave`` / ``adc_scan`` / ``refine_wave`` stage spans (and
       ``prefetch_wave`` when the batch issued the next micro-batch's
       objects mid-flight);
@@ -313,26 +311,8 @@ def emit_search_spans(tracer: Tracer, *, batch_events, batch_span_s: float,
     g = group or tracer.next_name("batch")
     q_count = len(timelines)
     tracer.span(g, f"batch[{q_count}q]", t0_s, batch_span_s, cat="batch",
-                args={"engine": engine, "pq": pq, "queries": q_count})
-
-    # per_query engine: the stream is serial on the batch clock — shift
-    # each query's schedule by the stream offset so the batch track (and
-    # the query tracks) read as the actual serial timeline.
-    offsets = [0.0] * q_count
-    if engine == "per_query":
-        off = 0.0
-        for qi in range(q_count):
-            offsets[qi] = off
-            off += latencies_s[qi]
-
-    if batch_events is not None:
-        _emit_timeline_events(tracer, g, batch_events, t0_s)
-        evs = batch_events
-    else:
-        for qi, tl in enumerate(timelines):
-            tracer.span(g, f"q{qi}", t0_s + offsets[qi],
-                        latencies_s[qi], cat="scan", args={"stage": 0})
-        evs = [ev for tl in timelines for ev in tl.events]
+                args={"pq": pq, "queries": q_count})
+    _emit_timeline_events(tracer, g, batch_events, t0_s)
 
     # stage spans on the batch track (async: they overlap compute)
     wave_names = [("fetch_wave", "io", 0), ("refine_wave", "io", 1)]
@@ -340,11 +320,11 @@ def emit_search_spans(tracer: Tracer, *, batch_events, batch_span_s: float,
                   ("refine_scan", "scan", 1)]
     for name, kind, stage in wave_names + (scan_names if pq else
                                            scan_names[:1]):
-        ext = _stage_extent(evs, kind, stage)
+        ext = _stage_extent(batch_events, kind, stage)
         if ext is not None:
             tracer.aspan(g, name, t0_s + ext[0], ext[1] - ext[0],
                          cat="stage")
-    pf = [(ev.t0_s, ev.t1_s) for ev in evs if _is_prefetch(ev)]
+    pf = [(ev.t0_s, ev.t1_s) for ev in batch_events if _is_prefetch(ev)]
     if pf:
         p0 = min(t for t, _ in pf)
         tracer.aspan(g, "prefetch_wave", t0_s + p0,
@@ -355,11 +335,8 @@ def emit_search_spans(tracer: Tracer, *, batch_events, batch_span_s: float,
         track = tracer.track(f"{g}/q{qi}")
         if track is None:
             continue                        # over the track cap
-        args = {"engine": engine}
-        if n_probes is not None:
-            args["n_probes"] = n_probes[qi]
-        tracer.span(track, f"query q{qi}", t0_s + offsets[qi],
-                    latencies_s[qi], cat="query", args=args)
-        _emit_timeline_events(tracer, track, tl.events,
-                              t0_s + offsets[qi])
+        args = {} if n_probes is None else {"n_probes": n_probes[qi]}
+        tracer.span(track, f"query q{qi}", t0_s, latencies_s[qi],
+                    cat="query", args=args)
+        _emit_timeline_events(tracer, track, tl.events, t0_s)
     return g

@@ -1,74 +1,16 @@
-"""Serving tier: the batched LM engine (prefill once, jitted greedy
-decode with a shared KV cache, per-sequence stop handling) and the ANN
-micro-batching front-end that feeds the batched DSANN data plane. The
-two halves of the RAG-serving integration (examples/rag_serve.py).
+"""Serving tier: the ANN micro-batching front-end that feeds the
+batch-coalesced DSANN data plane. The LM engine of the RAG-serving
+integration lives in ``serving.lm``.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
-from repro.models import decode_step, prefill
 from repro.obs import get_metrics, get_tracer, host_span
 from repro.obs.metrics import COUNT_BUCKETS
-
-
-@dataclasses.dataclass
-class ServeConfig:
-    max_new_tokens: int = 32
-    eos_id: int = -1           # -1: never stop early
-    temperature: float = 0.0   # 0 => greedy
-
-
-class Engine:
-    def __init__(self, cfg: ModelConfig, params, scfg: ServeConfig):
-        self.cfg = cfg
-        self.params = params
-        self.scfg = scfg
-        self._dec = jax.jit(
-            lambda p, t, c, i: decode_step(p, t, c, i, cfg))
-
-    def generate(self, batch: Dict[str, jax.Array],
-                 rng: Optional[jax.Array] = None) -> np.ndarray:
-        """batch: prompt inputs ({"tokens": [B, S]}, + modality stubs).
-        Returns generated token ids [B, <=max_new_tokens]."""
-        cfg, scfg = self.cfg, self.scfg
-        tokens = batch["tokens"]
-        b, s = tokens.shape
-        logits, cache = prefill(self.params, batch, cfg,
-                                max_len=s + scfg.max_new_tokens)
-        out = []
-        done = np.zeros(b, bool)
-        tok = self._sample(logits[:, -1:], rng)
-        for i in range(scfg.max_new_tokens):
-            out.append(np.asarray(tok[:, 0]))
-            if scfg.eos_id >= 0:
-                done |= out[-1] == scfg.eos_id
-                if done.all():
-                    break
-            logits, cache = self._dec(self.params, tok, cache, s + i)
-            tok = self._sample(logits, rng)
-        gen = np.stack(out, axis=1)
-        if scfg.eos_id >= 0:  # mask post-EOS tokens
-            seen = np.cumsum(gen == scfg.eos_id, axis=1) > 0
-            mask = np.concatenate(
-                [np.zeros((b, 1), bool), seen[:, :-1]], axis=1)
-            gen = np.where(mask, scfg.eos_id, gen)
-        return gen
-
-    def _sample(self, logits, rng):
-        logits = logits[:, :, : self.cfg.vocab_size]
-        if self.scfg.temperature <= 0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        assert rng is not None, "temperature sampling needs an rng"
-        return jax.random.categorical(
-            rng, logits / self.scfg.temperature, axis=-1).astype(jnp.int32)
 
 
 class AnnsFrontend:
@@ -77,7 +19,7 @@ class AnnsFrontend:
     Individually-submitted queries are buffered and flushed as batched
     ``search_pag`` calls (one chunk per ``max_batch`` tickets), so
     concurrent requests share the coalesced partition fetches (the
-    batched engine's cross-query dedup). ``submit`` returns a ticket;
+    data plane's cross-query dedup). ``submit`` returns a ticket;
     ``flush`` runs every buffered chunk and returns per-ticket
     ``(ids, d2, latency_s)``. An explicit ``max_batch`` caps request
     latency under heavy load: ``submit`` auto-flushes a full buffer

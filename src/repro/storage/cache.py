@@ -119,12 +119,12 @@ class PartitionCache:
             self.put(key, value)
 
     def account_shared(self, key: str, n_extra: int):
-        """Accounting hook for the batched data plane: ``n_extra`` probers
+        """Accounting hook for the coalesced wave: ``n_extra`` probers
         beyond the first were served by a single resident / in-flight copy
-        of ``key`` (cross-query coalescing). In the per-query plane each
-        of them would have been a cache lookup against the copy the first
-        prober inserted, so they count as hits — keeping hit-rate (and
-        the doorkeeper's popularity votes) comparable across engines."""
+        of ``key`` (cross-query coalescing). A prober served by the copy
+        the first prober fetched is a hit: the object was already local
+        when it was needed. Each such prober also casts a doorkeeper
+        popularity vote, as a lookup would."""
         if n_extra > 0:
             self.hits += n_extra
             if self._sketch is not None:

@@ -18,7 +18,7 @@ Pieces:
 * ``CircuitBreaker`` — per-shard closed -> open -> half-open machine.
   The cooldown is counted in *requests routed past the shard* rather
   than wall time: the simulator's event clock is per-query, so a
-  request-count cooldown keeps the breaker deterministic and engine-
+  request-count cooldown keeps the breaker deterministic and call-
   order independent while still modeling "stop hammering a dead shard,
   probe it occasionally".
 

@@ -324,7 +324,7 @@ class ComputeModel:
     def scan_batched(self, n_points: int, d: int, n_queries: int) -> float:
         """One coalesced partition scan serving n_queries probers: the
         distance flops scale with the probers, the per-partition dispatch
-        overhead is paid once (the batched-engine amortization)."""
+        overhead is paid once (the coalesced wave's amortization)."""
         return 3 * n_points * d * n_queries * self.sec_per_flop \
             + self.partition_overhead_s
 

@@ -8,6 +8,7 @@ from repro.core.distributed import ShardedServing
 from repro.core.search import SearchConfig, search_pag, write_partitions
 from repro.data.vectors import recall_at_k
 from repro.storage.cache import PartitionCache
+from repro.storage.resilience import ResiliencePolicy
 from repro.storage.simulator import FaultPlan, ObjectStore, StorageConfig
 
 
@@ -72,17 +73,20 @@ def test_cache_hit_masks_dead_shard(built_pag, small_ds):
         < sum(d.n_probes_lost for d in st_cold.degraded)
 
 
-@pytest.mark.parametrize("engine", ["batched", "per_query"])
-def test_corrupted_objects_never_cached(built_pag, small_ds, engine):
+@pytest.mark.parametrize("resilience", [None, ResiliencePolicy()],
+                         ids=["bare", "resilient"])
+def test_corrupted_objects_never_cached(built_pag, small_ds, resilience):
     """Payload corruption detected via the put-time checksum must not be
     admitted to the cache (a cached corrupt object would poison every
-    later hit)."""
+    later hit), on both admission paths of the coalesced wave: the bare
+    plane checks the checksum at admission, the resilient chain before
+    it hands a payload back."""
     plan = FaultPlan(corrupt_p=0.35, sticky=True, seed=2)
     store = ObjectStore(StorageConfig.preset("mem"), fault_plan=plan)
     write_partitions(built_pag, small_ds.base, store, n_shards=4)
     cache = PartitionCache(10 ** 9)
     cfg = SearchConfig(L=64, k=10, n_probe_max=64, cache=cache,
-                       engine=engine)
+                       resilience=resilience)
     search_pag(built_pag, small_ds.d, small_ds.queries, store, cfg,
                n_shards=4)
     assert cache._data                        # clean objects were cached
@@ -117,12 +121,10 @@ def test_recall_degrades_smoothly_with_dead_shards(built_pag, small_ds):
     store = ObjectStore(StorageConfig.preset("mem"))
     write_partitions(built_pag, small_ds.base, store, n_shards=S)
     store.kill_prefix("part/0/")
-    for engine in ("batched", "per_query"):
-        with pytest.raises(KeyError):
-            search_pag(built_pag, small_ds.d, small_ds.queries, store,
-                       SearchConfig(L=64, k=10, n_probe_max=64,
-                                    engine=engine),
-                       n_shards=S, dead_shard_fallback=False)
+    with pytest.raises(KeyError):
+        search_pag(built_pag, small_ds.d, small_ds.queries, store,
+                   SearchConfig(L=64, k=10, n_probe_max=64),
+                   n_shards=S, dead_shard_fallback=False)
 
 
 def test_hedging_improves_tail(built_pag, small_ds):

@@ -10,16 +10,10 @@ import pytest
 
 from repro.core.index import load_index, save_index
 from repro.core.pag import build_pag
-from repro.core.search import (
-    SearchConfig,
-    _pack_ids,
-    _unpack_ids,
-    search_pag,
-    write_partitions,
-)
+from repro.core.search import SearchConfig, search_pag, write_partitions
 from repro.core.distributed import ShardedServing
 from repro.data.vectors import make_dataset
-from repro.dataplane.plan import KeySpace
+from repro.dataplane.plan import KeySpace, pack_ids, unpack_ids
 from repro.kernels import ops, ref
 from repro.serving.engine import AnnsFrontend
 from repro.storage.resilience import ResiliencePolicy
@@ -171,7 +165,7 @@ def test_payload_round_trip_keeps_ids_to_2_31(dtype):
     ids, back = ks.unpack(obj)
     np.testing.assert_array_equal(ids, BIG_IDS)
     np.testing.assert_array_equal(back, vecs)
-    np.testing.assert_array_equal(_unpack_ids(_pack_ids(BIG_IDS, dtype)),
+    np.testing.assert_array_equal(unpack_ids(pack_ids(BIG_IDS, dtype)),
                                   BIG_IDS)
     empty_ids, empty = ks.unpack(ks.pack(BIG_IDS[:0], vecs[:0]))
     assert empty_ids.shape == (0,) and empty.shape == (0, 16)
@@ -203,7 +197,7 @@ def test_float32_objects_are_byte_identical_to_the_old_layout():
     assert store._crc == frozen._crc
     assert store.total_bytes() == frozen.total_bytes()
     ids = BIG_IDS.astype(np.int32)
-    assert _pack_ids(ids).tobytes() == ids.view(np.float32).tobytes()
+    assert pack_ids(ids).tobytes() == ids.view(np.float32).tobytes()
 
 
 # ------------------------------------------------- faults, replicas, PQ

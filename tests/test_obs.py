@@ -2,10 +2,12 @@
 invariant — ZERO effect on the data plane: search results and
 ``SearchStats`` must be bit-identical with tracing enabled, disabled,
 or never touched (the no-op default)."""
+import copy
 import json
 
 import numpy as np
 import pytest
+from reference_search import assert_matches_reference, reference_search
 
 from repro.core.search import (
     DegradedInfo,
@@ -23,13 +25,22 @@ from repro.obs.report import timeline_breakdown
 from repro.obs.trace import NOOP_TRACER, Tracer
 from repro.storage.simulator import ObjectStore, StorageConfig
 
-ENGINES = ("batched", "per_query")
+PLANES = ("none", "pq")     # SearchConfig.compression: float and PQ
+
+
+_WRITTEN = {}    # write_partitions kwargs -> a store as written
 
 
 def _mk_store(built_pag, small_ds, **kw):
-    store = ObjectStore(StorageConfig.preset("dfs", seed=1))
-    write_partitions(built_pag, small_ds.base, store, n_shards=4, **kw)
-    return store
+    """A fresh store as ``write_partitions`` leaves it (seeded, no call
+    drawn yet). Each layout is written once (PQ training is the slow
+    part) and deep-copied per call."""
+    key = tuple(sorted(kw.items()))
+    if key not in _WRITTEN:
+        store = ObjectStore(StorageConfig.preset("dfs", seed=1))
+        write_partitions(built_pag, small_ds.base, store, n_shards=4, **kw)
+        _WRITTEN[key] = store
+    return copy.deepcopy(_WRITTEN[key])
 
 
 def _search(built_pag, small_ds, store, **cfg_kw):
@@ -40,21 +51,25 @@ def _search(built_pag, small_ds, store, **cfg_kw):
 
 # ---------------------------------------------------------------- identity
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_tracing_disabled_is_bit_identical(built_pag, small_ds, engine):
+@pytest.mark.parametrize("compression", PLANES)
+def test_tracing_disabled_is_bit_identical(built_pag, small_ds,
+                                           compression):
     # fresh identically-seeded store per run: the simulator's latency
     # jitter RNG advances per call, so a shared store would differ
     # between runs regardless of tracing
     ids0, d20, st0 = _search(built_pag, small_ds,
-                             _mk_store(built_pag, small_ds),
-                             engine=engine)
+                             _mk_store(built_pag, small_ds,
+                                       compression=compression),
+                             compression=compression)
     with observe(tracer=Tracer(), metrics=MetricsRegistry()):
         ids1, d21, st1 = _search(built_pag, small_ds,
-                                 _mk_store(built_pag, small_ds),
-                                 engine=engine)
+                                 _mk_store(built_pag, small_ds,
+                                           compression=compression),
+                                 compression=compression)
     ids2, d22, st2 = _search(built_pag, small_ds,
-                             _mk_store(built_pag, small_ds),
-                             engine=engine)
+                             _mk_store(built_pag, small_ds,
+                                       compression=compression),
+                             compression=compression)
     np.testing.assert_array_equal(ids0, ids1)
     np.testing.assert_array_equal(d20, d21)
     np.testing.assert_array_equal(ids0, ids2)
@@ -64,14 +79,15 @@ def test_tracing_disabled_is_bit_identical(built_pag, small_ds, engine):
     assert st0.n_distinct_fetches == st1.n_distinct_fetches
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_root_span_matches_stats(built_pag, small_ds, engine):
+@pytest.mark.parametrize("compression", PLANES)
+def test_root_span_matches_stats(built_pag, small_ds, compression):
     """Tracer root spans ARE the stats: the batch root's duration equals
     ``batch_span_s`` and each query root equals its latency."""
-    store = _mk_store(built_pag, small_ds)
+    store = _mk_store(built_pag, small_ds, compression=compression)
     tr = Tracer()
     with observe(tracer=tr):
-        _, _, st = _search(built_pag, small_ds, store, engine=engine)
+        _, _, st = _search(built_pag, small_ds, store,
+                           compression=compression)
     (root,) = tr.roots("batch")
     assert root.dur_s == pytest.approx(st.batch_span_s, abs=1e-12)
     qroots = tr.roots("query")
@@ -80,12 +96,12 @@ def test_root_span_matches_stats(built_pag, small_ds, engine):
         assert s.dur_s == pytest.approx(lat, abs=1e-12)
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_child_spans_contained_in_parent(built_pag, small_ds, engine):
-    store = _mk_store(built_pag, small_ds)
+@pytest.mark.parametrize("compression", PLANES)
+def test_child_spans_contained_in_parent(built_pag, small_ds, compression):
+    store = _mk_store(built_pag, small_ds, compression=compression)
     tr = Tracer()
     with observe(tracer=tr):
-        _search(built_pag, small_ds, store, engine=engine)
+        _search(built_pag, small_ds, store, compression=compression)
     for root in tr.roots("batch") + tr.roots("query"):
         kids = [s for s in tr.spans
                 if s.track == root.track and s is not root]
@@ -98,21 +114,20 @@ def test_child_spans_contained_in_parent(built_pag, small_ds, engine):
         assert tiled <= root.dur_s + 1e-9
 
 
-def test_engines_trace_same_totals(built_pag, small_ds):
-    """Both engines, same seed: per-query latencies differ (different
-    I/O schedules) but each engine's root span equals its own stats —
-    and results agree bit-for-bit across engines."""
-    outs = {}
-    for engine in ENGINES:
-        store = _mk_store(built_pag, small_ds)
-        tr = Tracer()
-        with observe(tracer=tr):
-            ids, d2, st = _search(built_pag, small_ds, store,
-                                  engine=engine)
-        (root,) = tr.roots("batch")
-        assert root.dur_s == pytest.approx(st.batch_span_s, abs=1e-12)
-        outs[engine] = ids
-    np.testing.assert_array_equal(outs["batched"], outs["per_query"])
+def test_trace_root_matches_stats_and_reference(built_pag, small_ds):
+    """A traced batch: its root span equals the stats' batch span and
+    names its plane, and the traced answers are the plain reference's."""
+    store = _mk_store(built_pag, small_ds)
+    tr = Tracer()
+    with observe(tracer=tr):
+        ids, d2, st = _search(built_pag, small_ds, store)
+    (root,) = tr.roots("batch")
+    assert root.dur_s == pytest.approx(st.batch_span_s, abs=1e-12)
+    assert root.args == {"pq": False, "queries": 16}
+    q = small_ds.queries[:16]
+    ref = reference_search(built_pag, small_ds.base, q,
+                           SearchConfig(L=32, k=10, n_probe_max=16))
+    assert_matches_reference(ids, d2, ref, small_ds.base, q)
 
 
 # ------------------------------------------------------------------- trace

@@ -1,19 +1,18 @@
-"""Batched data plane: batched-vs-per-query equivalence, coalesced fetch
-accounting, k-larger-than-pool / all-shards-dead edge cases, and the
-micro-batching serving front-end."""
-import dataclasses
-
+"""Batched data plane: answers against the plain reference, coalesced
+fetch accounting, k-larger-than-pool / all-shards-dead edge cases, and
+the micro-batching serving front-end."""
 import numpy as np
 import pytest
+from reference_search import assert_matches_reference, reference_search
 
 from repro.core.search import (
     ID_SENTINEL,
     INF,
     SearchConfig,
-    _dedup_first,
     search_pag,
     write_partitions,
 )
+from repro.dataplane.scan import dedup_first
 from repro.storage.simulator import ObjectStore, StorageConfig
 
 
@@ -23,23 +22,22 @@ def _fresh_store(built_pag, ds, kind="dfs", seed=7, n_shards=4):
     return store
 
 
-# ---------------------------------------------------------------- equivalence
+# ------------------------------------------------------------------ reference
 
-def test_batched_equals_per_query(built_pag, small_ds):
-    """Same queries, same probes => identical (ids, d2) and identical
-    per-query n_probes / n_hops across the two engines."""
+def test_search_matches_reference(built_pag, small_ds):
+    """The coalesced plane returns the exact top-k of the beam plus the
+    probed partitions, as the plain reference reads them from the base
+    array; every probe is served, and each query ran its own hops."""
     cfg = SearchConfig(L=64, k=10, n_probe_max=32, mode="async")
-    ids_b, d2_b, st_b = search_pag(
-        built_pag, small_ds.d, small_ds.queries,
-        _fresh_store(built_pag, small_ds), cfg, n_shards=4)
-    cfg_pq = dataclasses.replace(cfg, engine="per_query")
-    ids_p, d2_p, st_p = search_pag(
-        built_pag, small_ds.d, small_ds.queries,
-        _fresh_store(built_pag, small_ds), cfg_pq, n_shards=4)
-    assert np.array_equal(ids_b, ids_p)
-    assert np.array_equal(d2_b, d2_p)
-    assert st_b.n_probes == st_p.n_probes
-    assert st_b.n_hops == st_p.n_hops
+    q = small_ds.queries
+    ids, d2, st = search_pag(built_pag, small_ds.d, q,
+                             _fresh_store(built_pag, small_ds), cfg,
+                             n_shards=4)
+    ref = reference_search(built_pag, small_ds.base, q, cfg)
+    assert_matches_reference(ids, d2, ref, small_ds.base, q)
+    assert all(d.n_probes_lost == 0 for d in st.degraded)
+    assert st.n_probes == [d.n_probes_wanted for d in st.degraded]
+    assert len(st.n_hops) == len(q) and min(st.n_hops) > 0
 
 
 def test_batched_dedups_fetches(built_pag, small_ds):
@@ -57,18 +55,21 @@ def test_batched_dedups_fetches(built_pag, small_ds):
 
 
 def test_batched_throughput_wins(built_pag, small_ds):
-    """The batched engine's simulated batch throughput beats the seed
-    per-query serial stream by >= 3x on DFS-tier storage."""
+    """A coalesced batch's simulated throughput beats a serial stream of
+    the same queries sent one at a time (no cross-query coalescing) by
+    >= 3x on DFS-tier storage."""
     cfg = SearchConfig(L=64, k=10, n_probe_max=32, mode="async")
-    _, _, st_b = search_pag(built_pag, small_ds.d, small_ds.queries,
+    q = small_ds.queries[:32]
+    _, _, st_b = search_pag(built_pag, small_ds.d, q,
                             _fresh_store(built_pag, small_ds), cfg,
                             n_shards=4)
-    cfg_pq = dataclasses.replace(cfg, engine="per_query")
-    _, _, st_p = search_pag(built_pag, small_ds.d, small_ds.queries,
-                            _fresh_store(built_pag, small_ds), cfg_pq,
-                            n_shards=4)
-    assert st_b.batch_qps() > 3 * st_p.batch_qps(), (
-        st_b.batch_qps(), st_p.batch_qps())
+    store = _fresh_store(built_pag, small_ds)
+    serial_s = sum(search_pag(built_pag, small_ds.d, q[i:i + 1], store, cfg,
+                              n_shards=4)[2].batch_span_s
+                   for i in range(len(q)))
+    serial_qps = len(q) / serial_s
+    assert st_b.batch_qps() > 3 * serial_qps, (st_b.batch_qps(),
+                                               serial_qps)
 
 
 # ------------------------------------------------------------------ edge cases
@@ -98,38 +99,36 @@ def test_k_larger_than_candidate_pool(small_ds):
 
 def test_all_shards_dead_degraded(built_pag, small_ds):
     """dead_shard_fallback=True with every shard down must return padded
-    beam-only results, not raise — for both engines."""
-    for engine in ("batched", "per_query"):
-        store = _fresh_store(built_pag, small_ds, kind="mem")
-        store.kill_prefix("part/")
-        cfg = SearchConfig(L=64, k=10, n_probe_max=32, engine=engine)
-        ids, d2, st = search_pag(built_pag, small_ds.d,
-                                 small_ds.queries, store, cfg,
-                                 n_shards=4, dead_shard_fallback=True)
-        assert (np.asarray(st.n_probes) == 0).all()
-        assert (ids >= -1).all()
-        assert (ids[:, 0] >= 0).all()  # beam still yields candidates
-        assert st.n_distinct_fetches == 0
+    beam-only results, not raise."""
+    store = _fresh_store(built_pag, small_ds, kind="mem")
+    store.kill_prefix("part/")
+    cfg = SearchConfig(L=64, k=10, n_probe_max=32)
+    ids, d2, st = search_pag(built_pag, small_ds.d, small_ds.queries,
+                             store, cfg, n_shards=4,
+                             dead_shard_fallback=True)
+    assert (np.asarray(st.n_probes) == 0).all()
+    assert (ids >= -1).all()
+    assert (ids[:, 0] >= 0).all()  # beam still yields candidates
+    assert st.n_distinct_fetches == 0
 
 
 def test_dead_shard_raises_without_fallback(built_pag, small_ds):
-    for engine in ("batched", "per_query"):
-        store = _fresh_store(built_pag, small_ds, kind="mem")
-        store.kill_prefix("part/0/")
-        cfg = SearchConfig(L=64, k=10, n_probe_max=32, engine=engine)
-        with pytest.raises(KeyError):
-            search_pag(built_pag, small_ds.d, small_ds.queries, store,
-                       cfg, n_shards=4, dead_shard_fallback=False)
+    store = _fresh_store(built_pag, small_ds, kind="mem")
+    store.kill_prefix("part/0/")
+    cfg = SearchConfig(L=64, k=10, n_probe_max=32)
+    with pytest.raises(KeyError):
+        search_pag(built_pag, small_ds.d, small_ds.queries, store, cfg,
+                   n_shards=4, dead_shard_fallback=False)
 
 
 def test_dedup_sentinel():
     """Invalid ids (< 0) map to the 2**62 sentinel and are dropped;
     duplicates keep their first occurrence only."""
     ids = np.array([7, -1, 3, 7, 3, 12, -1], np.int64)
-    keep = _dedup_first(ids)
+    keep = dedup_first(ids)
     assert keep.tolist() == [True, False, True, False, False, True, False]
     assert ID_SENTINEL == 2 ** 62
-    assert (_dedup_first(np.array([-1, -1], np.int64)) == False).all()  # noqa: E712
+    assert (dedup_first(np.array([-1, -1], np.int64)) == False).all()  # noqa: E712
 
 
 # ------------------------------------------------------- latency accounting
@@ -195,7 +194,7 @@ def test_get_hedged_matches_min_semantics():
 
 
 def test_shared_fetch_charged_to_every_prober(built_pag, small_ds):
-    """Repeat the same query: the batched engine fetches its partitions
+    """Repeat the same query: the coalesced wave fetches its partitions
     once but charges both probers, so both rows see identical nonzero
     probe counts and (same-draw) latencies."""
     q = np.repeat(small_ds.queries[:1], 2, axis=0)

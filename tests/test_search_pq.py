@@ -1,18 +1,15 @@
-"""Compressed data plane (v2 PQ payloads): two-stage batched scan,
-engine agreement, byte savings, id bit-cast, and fault semantics."""
+"""Compressed data plane (v2 PQ payloads): two-stage batched scan
+against the plain reference, byte savings, id bit-cast, and fault
+semantics."""
 import dataclasses
 
 import numpy as np
 import pytest
+from reference_search import assert_matches_reference, reference_search
 
 from repro.core.pag import build_pag
-from repro.core.search import (
-    SearchConfig,
-    _pack_ids,
-    _unpack_ids,
-    search_pag,
-    write_partitions,
-)
+from repro.core.search import SearchConfig, search_pag, write_partitions
+from repro.dataplane.plan import pack_ids, unpack_ids
 from repro.storage.cache import PartitionCache
 from repro.storage.resilience import ResiliencePolicy, replica_keys
 from repro.storage.simulator import FaultPlan, ObjectStore, StorageConfig
@@ -38,9 +35,9 @@ def pq_env():
     d2 = ((x[None] - q[:, None]) ** 2).sum(-1)
     gt = np.argsort(d2, axis=1)[:, :10]
     store = ObjectStore(StorageConfig.preset("dfs"))
-    write_partitions(pag, x, store, n_shards=S, compression="pq",
-                     pq_m=PQ_M)
-    return pag, x, q, gt, store
+    cb = write_partitions(pag, x, store, n_shards=S, compression="pq",
+                          pq_m=PQ_M)
+    return pag, x, q, gt, store, cb
 
 
 def _recall10(ids, gt):
@@ -48,41 +45,47 @@ def _recall10(ids, gt):
                           for i in range(len(gt))]))
 
 
-def test_engines_agree_on_compressed_plane(pq_env):
-    """Acceptance: batched and per_query return identical results under
-    compression="pq" (shared ADC selection + shared exact rerank)."""
-    pag, x, q, gt, store = pq_env
-    kw = dict(compression="pq", rerank_k=16, n_probe_max=32)
-    ids_b, d2_b, _ = search_pag(pag, D, q, store,
-                                SearchConfig(engine="batched", **kw),
-                                n_shards=S)
-    ids_p, d2_p, _ = search_pag(pag, D, q, store,
-                                SearchConfig(engine="per_query", **kw),
-                                n_shards=S)
-    np.testing.assert_array_equal(ids_b, ids_p)
-    np.testing.assert_allclose(d2_b, d2_p, rtol=1e-6)
+def test_pq_plane_matches_reference(pq_env):
+    """Acceptance: under compression="pq" the plane returns the exact
+    top-k of the beam plus the partitions covering each query's ADC-top
+    ``rerank_k``, as the plain reference ranks and reads them."""
+    pag, x, q, gt, store, cb = pq_env
+    cfg = SearchConfig(compression="pq", rerank_k=16, n_probe_max=32)
+    ids, d2, st = search_pag(pag, D, q, store, cfg, n_shards=S)
+    ref = reference_search(pag, x, q, cfg, codebook=cb)
+    assert_matches_reference(ids, d2, ref, x, q)
+    assert all(d.n_probes_lost == 0 for d in st.degraded)
 
 
 def test_pq_cuts_bytes_8x_with_recall_within_1pct(pq_env):
     """Acceptance: on the DFS profile the compressed plane fetches >= 8x
     fewer bytes per query than the float plane, with recall@10 within 1%
-    (exact rerank). per_query engine = honest per-query byte accounting
-    (no cross-query coalescing amortization)."""
-    pag, x, q, gt, store = pq_env
-    nq = len(q)
+    (exact rerank). Queries go one at a time, so no cross-query
+    coalescing amortizes either bill."""
+    pag, x, q, gt, store, cb = pq_env
+    q, gt = q[:32], gt[:32]
 
-    b0 = store.bytes_fetched
-    ids_f, _, _ = search_pag(
-        pag, D, q, store,
-        SearchConfig(engine="per_query", n_probe_max=32), n_shards=S)
-    bytes_float = (store.bytes_fetched - b0) / nq
+    def one_at_a_time(cfg):
+        """(ids, bytes fetched per query) of ``q`` sent one by one. The
+        PQ codebook is index metadata that every call fetches again; the
+        stream counts it once, as one batch of the same queries does."""
+        rows, nbytes = [], 0
+        for i in range(len(q)):
+            b0 = store.bytes_fetched
+            rows.append(search_pag(pag, D, q[i:i + 1], store, cfg,
+                                   n_shards=S)[0])
+            nbytes += store.bytes_fetched - b0
+        if cfg.compression == "pq":
+            nbytes -= (len(q) - 1) * cb.centroids.nbytes
+        return np.concatenate(rows), nbytes / len(q)
 
-    b0 = store.bytes_fetched
-    ids_c, _, _ = search_pag(
-        pag, D, q, store,
-        SearchConfig(engine="per_query", compression="pq", rerank_k=64,
-                     n_probe_max=32), n_shards=S)
-    bytes_pq = (store.bytes_fetched - b0) / nq
+    # a wide scan block only rounds launch widths coarser (fewer shapes
+    # to compile one query at a time); the fetched bytes do not see it
+    ids_f, bytes_float = one_at_a_time(
+        SearchConfig(n_probe_max=32, scan_block=2048))
+    ids_c, bytes_pq = one_at_a_time(
+        SearchConfig(compression="pq", rerank_k=64, n_probe_max=32,
+                     scan_block=2048))
 
     ratio = bytes_float / bytes_pq
     r_f, r_c = _recall10(ids_f, gt), _recall10(ids_c, gt)
@@ -93,7 +96,7 @@ def test_pq_cuts_bytes_8x_with_recall_within_1pct(pq_env):
 def test_pack_unpack_ids_exact_beyond_2pow24():
     ids = np.array([0, 1, 2 ** 24 + 1, 2 ** 24 + 12345, 2 ** 31 - 1],
                    np.int64)
-    assert (_unpack_ids(_pack_ids(ids)) == ids).all()
+    assert (unpack_ids(pack_ids(ids)) == ids).all()
     # the old float VALUE cast loses exactly these ids
     assert (ids.astype(np.float32).astype(np.int64) != ids).any()
 
@@ -149,7 +152,7 @@ def test_billion_scale_ids_survive_storage(built_pag, small_ds,
 
 
 def test_lost_code_object_degrades_like_lost_partition(pq_env):
-    pag, x, q, gt, store = pq_env
+    pag, x, q, gt, store, _ = pq_env
     # kill the PRIMARY code object of every partition on shard 0 (their
     # float siblings survive: the probe wave still can't use them)
     for pid in range(pag.n_parts):
@@ -168,7 +171,7 @@ def test_lost_code_object_degrades_like_lost_partition(pq_env):
 
 
 def test_lost_codebook_degrades_to_beam_only(pq_env):
-    pag, x, q, gt, store = pq_env
+    pag, x, q, gt, store, _ = pq_env
     store.kill_prefix("part/meta/pq_codebook")
     try:
         cfg = SearchConfig(compression="pq", rerank_k=16, n_probe_max=32)
@@ -185,15 +188,13 @@ def test_lost_codebook_degrades_to_beam_only(pq_env):
 
 
 def test_corrupt_codes_never_cached(pq_env):
-    pag, x, q, gt, store = pq_env
+    pag, x, q, gt, store, _ = pq_env
     store.set_fault_plan(FaultPlan(corrupt_p=1.0, sticky=True, seed=3))
     try:
         cache = PartitionCache(64 * 1024 * 1024)
-        for engine in ("batched", "per_query"):
-            cfg = SearchConfig(compression="pq", rerank_k=16,
-                               n_probe_max=32, engine=engine,
-                               cache=cache)
-            search_pag(pag, D, q, store, cfg, n_shards=S)
+        cfg = SearchConfig(compression="pq", rerank_k=16, n_probe_max=32,
+                           cache=cache)
+        search_pag(pag, D, q, store, cfg, n_shards=S)
         assert len(cache._data) == 0    # nothing corrupt admitted
     finally:
         store.set_fault_plan(None)
@@ -203,7 +204,7 @@ def test_corrupt_codes_recovered_by_replicas(pq_env):
     """Transient corruption: the resilient chain detects it against the
     put-time checksum, retries / fails over to clean replicas, and the
     results match the clean run exactly."""
-    pag, x, q, gt, store = pq_env
+    pag, x, q, gt, store, _ = pq_env
     clean_cfg = SearchConfig(compression="pq", rerank_k=16,
                              n_probe_max=32)
     ids_clean, _, _ = search_pag(pag, D, q, store, clean_cfg, n_shards=S)
@@ -224,7 +225,7 @@ def test_corrupt_codes_recovered_by_replicas(pq_env):
 
 
 def test_v2_payload_layout(pq_env):
-    pag, x, q, gt, store = pq_env
+    pag, x, q, gt, store, _ = pq_env
     store2 = ObjectStore(StorageConfig.preset("mem"))
     cb = write_partitions(pag, x, store2, n_shards=S, replicas=2,
                           compression="pq", pq_m=PQ_M)
@@ -246,7 +247,7 @@ def test_v2_payload_layout(pq_env):
 
 
 def test_cache_stats_surface_in_search_stats(pq_env):
-    pag, x, q, gt, store = pq_env
+    pag, x, q, gt, store, _ = pq_env
     cache = PartitionCache(64 * 1024 * 1024)
     cfg = SearchConfig(compression="pq", rerank_k=16, n_probe_max=32,
                        cache=cache)

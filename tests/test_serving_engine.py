@@ -6,7 +6,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import forward, init_params
-from repro.serving.engine import Engine, ServeConfig
+from repro.serving.lm import Engine, ServeConfig
 
 
 def test_greedy_matches_forward_argmax():
